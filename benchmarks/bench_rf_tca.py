@@ -112,9 +112,9 @@ def large_n_perf(n_features: int = 2048, n: int = 512) -> dict:
     from repro.core.rf_tca import streaming_gram
     from repro.kernels import ops as kops
 
-    plan = kops.gram_tile_plan(n_features)
-    rng = np.random.default_rng(0)
     p = 16
+    plan = kops.gram_tile_plan(n_features, p)
+    rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(p, n)), jnp.float32)
     ell = ell_vector(n // 2, n - n // 2)
     omega = jnp.asarray(rng.normal(size=(n_features, p)), jnp.float32)
@@ -140,7 +140,7 @@ def large_n_perf(n_features: int = 2048, n: int = 512) -> dict:
         "u_abs_err": float(jnp.abs(u_p - u_t).max()),
         # what the tiling buys: per-instance accumulator bytes vs untiled
         "acc_bytes_tiled": plan["acc_bytes"],
-        "acc_bytes_untiled": kops.gram_tile_plan(n_features, tile=0)["acc_bytes"],
+        "acc_bytes_untiled": kops.gram_tile_plan(n_features, p, tile=0)["acc_bytes"],
     }
     emit(
         "fig3/gram_large_N", out["tiled_pallas_s"] * 1e6,
@@ -168,7 +168,7 @@ def _fused_memory_proxy(n_features: int, p: int = 16, ensemble: int = 1) -> dict
     interpret-mode CI can run."""
     from repro.kernels import ops as kops
 
-    plan = kops.gram_tile_plan(n_features)
+    plan = kops.gram_tile_plan(n_features, p, fused=True)
     npad = plan["n_pad"]
     p_pad = p + (-p) % 128
     stats = 4 * (3 * npad * npad + 2 * npad * 2 * ensemble)

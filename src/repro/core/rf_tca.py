@@ -27,10 +27,9 @@ which replaces the Cholesky factorization + two triangular solves with two
 rank-one updates (O(N^2) instead of O(N^3)).  The whitened operator
 C = B^{-1/2} G_H B^{-1/2} is then diagonalized by:
 
-- ``solver="eigh"``   — direct symmetric eigendecomposition.  When running
-  outside jit with SciPy available, only the top-m eigenpairs are computed
-  (LAPACK ``syevr`` subset — much cheaper than a full ``eigh``).  Best up to
-  2N ~ a few thousand; bitwise-deterministic.
+- ``solver="eigh"``   — host SciPy symmetric eigendecomposition of the top-m
+  eigenpairs only (LAPACK ``syevr`` subset — much cheaper than a full
+  ``eigh``).  Best up to 2N ~ a few thousand; bitwise-deterministic.
 - ``solver="lobpcg"`` — matrix-free top-m LOBPCG
   (``jax.experimental.sparse.linalg.lobpcg_standard``) that only applies
   C·v products (O(N^2 m) per iteration).  Pick this when 2N is large enough
@@ -58,11 +57,6 @@ from repro.core.kernels_math import (
 )
 from repro.core.rff import draw_omega, rff_features
 
-try:  # SciPy is optional: only used for the host-side subset-eigh fast path
-    from scipy.linalg import eigh as _scipy_eigh
-except ImportError:  # pragma: no cover - container always ships SciPy
-    _scipy_eigh = None
-
 
 class RFTCAState(NamedTuple):
     omega: jnp.ndarray | None  # (N, p) frequency matrix; None on the fused path
@@ -71,6 +65,12 @@ class RFTCAState(NamedTuple):
     # seed-fused spec (seed, ensemble, sigma, kernel) when omega is None: the
     # frequency matrix is a pure function of these and is re-drawn on demand
     fused: tuple | None = None
+
+
+def _mm(a, b):
+    """a @ b at full fp32 precision: on TPU the default is one bf16 pass,
+    too coarse for phases Omega X that reach |z| ~ sqrt(p) / sigma."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 # --------------------------------------------------------------------------
@@ -104,7 +104,7 @@ def _gram_stream_body(x: jnp.ndarray, ell: jnp.ndarray, omega: jnp.ndarray, *, b
     def body(carry, inp):
         cc, cs, ss, u_c, u_s, s_c, s_s = carry
         xblk, elb, mkb = inp
-        z = (omega @ xblk.T).astype(jnp.float32)
+        z = _mm(omega, xblk.T).astype(jnp.float32)
         # unscaled features; the 1/sqrt(N) normalization is folded into the
         # final statistics (quadratic for G, linear for u and the column sum)
         c = jnp.cos(z)
@@ -113,11 +113,11 @@ def _gram_stream_body(x: jnp.ndarray, ell: jnp.ndarray, omega: jnp.ndarray, *, b
             c = c * mkb[None, :]  # zero out padded sample columns
             s = s * mkb[None, :]
         return (
-            cc + c @ c.T,
-            cs + c @ s.T,
-            ss + s @ s.T,
-            u_c + c @ elb,
-            u_s + s @ elb,
+            cc + _mm(c, c.T),
+            cs + _mm(c, s.T),
+            ss + _mm(s, s.T),
+            u_c + _mm(c, elb),
+            u_s + _mm(s, elb),
             s_c + jnp.sum(c, axis=1),
             s_s + jnp.sum(s, axis=1),
         ), None
@@ -140,7 +140,7 @@ _gram_stream_xla = jax.jit(_gram_stream_body, static_argnames=("block",))
 
 def _tile_featurize(om_i, xblk, mkb):
     """Unscaled masked cos/sin slabs of one feature tile on one sample block."""
-    z = (om_i @ xblk.T).astype(jnp.float32)
+    z = _mm(om_i, xblk.T).astype(jnp.float32)
     return jnp.cos(z) * mkb[None, :], jnp.sin(z) * mkb[None, :]
 
 
@@ -155,7 +155,7 @@ def _tile_pair_stats(om_i, om_j, xb, mb):
         xblk, mkb = inp
         c_i, s_i = _tile_featurize(om_i, xblk, mkb)
         c_j, s_j = _tile_featurize(om_j, xblk, mkb)
-        return (cc + c_i @ c_j.T, cs + c_i @ s_j.T, ss + s_i @ s_j.T), None
+        return (cc + _mm(c_i, c_j.T), cs + _mm(c_i, s_j.T), ss + _mm(s_i, s_j.T)), None
 
     init = tuple(jnp.zeros((tile, tile), jnp.float32) for _ in range(3))
     (cc, cs, ss), _ = jax.lax.scan(body, init, (xb, mb))
@@ -171,8 +171,8 @@ def _tile_row_moments(om_i, xb, eb, mb):
         xblk, elb, mkb = inp
         c_i, s_i = _tile_featurize(om_i, xblk, mkb)
         return (
-            u_c + c_i @ elb,
-            u_s + s_i @ elb,
+            u_c + _mm(c_i, elb),
+            u_s + _mm(s_i, elb),
             s_c + jnp.sum(c_i, axis=1),
             s_s + jnp.sum(s_i, axis=1),
         ), None
@@ -238,16 +238,25 @@ def _fused_blocks(x, ell, *, block: int, nf_mult: int, n_features: int):
     Identical padded shapes are a precondition for bit-for-bit agreement —
     the fused draw covers padded rows/cols too, and only identical block
     geometry makes the twin trace the same float ops as the kernel."""
+    from repro.kernels.rff_gram_stream import DRAW_COLS
+
     p, n = x.shape
     pad_n = (-n) % block
     lm = jnp.stack([ell.astype(x.dtype), jnp.ones((n,), x.dtype)])  # (2, n)
-    xp = jnp.pad(x, ((0, (-p) % block), (0, pad_n)))
+    xp = jnp.pad(x, ((0, (-p) % DRAW_COLS), (0, pad_n)))
     lmp = jnp.pad(lm, ((0, 0), (0, pad_n)))
     nb = (n + pad_n) // block
     xb = xp.reshape(xp.shape[0], nb, block).transpose(1, 0, 2)  # (nb, p_pad, bk)
     lmb = lmp.reshape(2, nb, block).transpose(1, 0, 2)  # (nb, 2, bk)
     nf_pad = n_features + (-n_features) % nf_mult
     return xb, lmb, nf_pad
+
+
+def _block_loader(xblk):
+    """The twins' ``load_x``: DRAW_COLS rows of a (p_pad, bk) sample block."""
+    from repro.kernels.rff_gram_stream import DRAW_COLS
+
+    return lambda c0: jax.lax.dynamic_slice_in_dim(xblk, c0, DRAW_COLS, 0)
 
 
 def _gram_stream_fused_body(
@@ -274,7 +283,7 @@ def _gram_stream_fused_body(
     def body(carry, inp):
         xblk, lmk = inp
         d = fused_step_stats(
-            xblk, lmk, nf=nf_pad, n_features=n_features, seed=seed,
+            _block_loader(xblk), xblk.shape[0], lmk, nf=nf_pad, n_features=n_features, seed=seed,
             ensemble=ensemble, sigma=sigma, rf_kernel=rf_kernel,
         )
         return tuple(a + t for a, t in zip(carry, d)), None
@@ -306,8 +315,8 @@ def _gram_stream_fused_tiled_body(
 ):
     """Tiled-layout XLA twin of the seed-fused Pallas kernel: ``lax.map`` over
     (i, j) feature-tile pairs with the sample scan innermost, each pair
-    re-drawing its two (t, p_pad) weight slabs per step from the counter
-    stream — the tiled kernel's loop nest and memory profile, nothing N-sized
+    re-drawing its two row tiles per step from the counter stream, DRAW_COLS
+    columns at a time — the tiled kernel's loop nest, nothing N-sized
     live beyond the output statistics."""
     from repro.kernels.rff_gram_stream import (
         fused_tile_moment_step,
@@ -331,7 +340,9 @@ def _gram_stream_fused_tiled_body(
 
         def body(carry, inp):
             xblk, lmk = inp
-            d = fused_tile_pair_step(xblk, lmk, row_i, row_j, **kw)
+            d = fused_tile_pair_step(
+                _block_loader(xblk), xblk.shape[0], lmk, row_i, row_j, **kw
+            )
             return tuple(a + t for a, t in zip(carry, d)), None
 
         init = tuple(jnp.zeros((tile, tile), jnp.float32) for _ in range(3))
@@ -341,7 +352,9 @@ def _gram_stream_fused_tiled_body(
     def row_moments(i):
         def body(carry, inp):
             xblk, lmk = inp
-            d = fused_tile_moment_step(xblk, lmk, i * tile, **kw)
+            d = fused_tile_moment_step(
+                _block_loader(xblk), xblk.shape[0], lmk, i * tile, **kw
+            )
             return tuple(a + t for a, t in zip(carry, d)), None
 
         init = tuple(jnp.zeros((tile, mw), jnp.float32) for _ in range(2))
@@ -396,7 +409,9 @@ def fused_streaming_gram(
             x, ell, n_features=n_features, seed=seed, ensemble=ensemble,
             sigma_rf=sigma, rf_kernel=rf_kernel, block=block, tile=tile,
         )
-    plan_tile = kops.gram_tile_plan(n_features, tile=tile)["tile"]
+    plan_tile = kops.gram_tile_plan(
+        n_features, x.shape[0], tile=tile, fused=True, block=block
+    )["tile"]
     if plan_tile is None:
         return _gram_stream_fused_xla(
             x, ell, n_features=n_features, seed=seed, ensemble=ensemble,
@@ -465,13 +480,13 @@ def _whiten_half(u: jnp.ndarray, gamma: float) -> Callable[[jnp.ndarray], jnp.nd
     c = sqrt(gamma / (gamma + |u|^2)) - 1.  Applying it is two rank-one
     updates, O(N k) for a (2N, k) block — no Cholesky, no triangular solves.
     """
-    uu = u @ u
+    uu = _mm(u, u)
     c = jnp.sqrt(gamma / (gamma + uu)) - 1.0
     uhat = u * jax.lax.rsqrt(uu + 1e-30)
     inv_sqrt_gamma = jax.lax.rsqrt(jnp.asarray(gamma, u.dtype))
 
     def apply(v: jnp.ndarray) -> jnp.ndarray:
-        return (v + c * jnp.outer(uhat, uhat @ v)) * inv_sqrt_gamma
+        return (v + c * jnp.outer(uhat, _mm(uhat, v))) * inv_sqrt_gamma
 
     return apply
 
@@ -493,7 +508,7 @@ def _solve_whitened_top_m(g_h, u, gamma, key, *, m: int, iters: int, tol):
         from jax.experimental.sparse.linalg import lobpcg_standard
 
         def matvec(v):
-            return bihalf(g_h @ bihalf(v))
+            return bihalf(_mm(g_h, bihalf(v)))
 
         x0 = jax.random.normal(key, (g_h.shape[0], m), g_h.dtype)
         vals, vecs, _ = lobpcg_standard(matvec, x0, m=iters, tol=tol)
@@ -510,9 +525,10 @@ _lobpcg_solve = functools.partial(
 def _host_top_eigh(cmat, *, m: int):
     """Host-side LAPACK subset eigendecomposition (syevr): top-m pairs only."""
     import numpy as np
+    from scipy.linalg import eigh
 
     two_n = cmat.shape[0]
-    vals, vecs = _scipy_eigh(
+    vals, vecs = eigh(
         np.asarray(cmat, np.float32), subset_by_index=[two_n - m, two_n - 1]
     )
     return (
@@ -522,32 +538,27 @@ def _host_top_eigh(cmat, *, m: int):
 
 
 def _top_eigh(cmat, m: int):
-    """Top-m (vals desc, vecs) of a symmetric matrix.
+    """Top-m (vals desc, vecs) of a symmetric matrix, on the host.
 
-    With SciPy present this routes to the LAPACK subset driver (syevr),
-    which only back-transforms the m requested eigenvectors and is several
-    times faster than a full ``eigh`` at bench sizes.  On concrete arrays
-    SciPy is called directly AFTER the XLA program has finished — running it
-    as an in-program callback stalls it badly (XLA's spin-waiting worker
-    threads starve the single-threaded LAPACK call).  Under tracing it
-    becomes a ``pure_callback``; without SciPy: full jnp eigh.
+    The LAPACK subset driver (syevr) only back-transforms the m requested
+    eigenvectors and is several times faster than a full ``eigh`` at bench
+    sizes.  On concrete arrays SciPy is called directly AFTER the XLA program
+    has finished — running it as an in-program callback stalls it badly
+    (XLA's spin-waiting worker threads starve the single-threaded LAPACK
+    call).  Under tracing it becomes a ``pure_callback``.
     """
-    two_n = cmat.shape[0]
-    if _scipy_eigh is not None:
-        if not isinstance(cmat, jax.core.Tracer):
-            import numpy as np
+    if not isinstance(cmat, jax.core.Tracer):
+        import numpy as np
 
-            vals, vecs = _host_top_eigh(np.asarray(cmat), m=m)
-            return jnp.asarray(vals), jnp.asarray(vecs)
-        out_shapes = (
-            jax.ShapeDtypeStruct((m,), jnp.float32),
-            jax.ShapeDtypeStruct((two_n, m), jnp.float32),
-        )
-        return jax.pure_callback(
-            functools.partial(_host_top_eigh, m=m), out_shapes, cmat.astype(jnp.float32)
-        )
-    vals, vecs = jnp.linalg.eigh(cmat)
-    return vals[::-1][:m], vecs[:, ::-1][:, :m]
+        vals, vecs = _host_top_eigh(np.asarray(cmat), m=m)
+        return jnp.asarray(vals), jnp.asarray(vecs)
+    out_shapes = (
+        jax.ShapeDtypeStruct((m,), jnp.float32),
+        jax.ShapeDtypeStruct((cmat.shape[0], m), jnp.float32),
+    )
+    return jax.pure_callback(
+        functools.partial(_host_top_eigh, m=m), out_shapes, cmat.astype(jnp.float32)
+    )
 
 
 @jax.jit
@@ -899,6 +910,19 @@ def fused_omega_cache_info() -> dict[str, int]:
     }
 
 
+def project_features(w_rf: jnp.ndarray, feats: jnp.ndarray) -> jnp.ndarray:
+    """W_RF^T Sigma (m, n) as one contraction over the 2N axis.
+
+    Written as the ``dot_general`` a jitted ``w_rf.T @ feats`` compiles to,
+    so the eager transform and the serving planes run the same dot: an eager
+    ``.T`` would materialize the transpose and take a dot whose summation
+    order differs in the last bit.
+    """
+    return jax.lax.dot_general(
+        w_rf, feats, (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST
+    )
+
+
 def rf_tca_transform(state: RFTCAState, x: jnp.ndarray) -> jnp.ndarray:
     """F = W_RF^T Sigma(X) in R^{m x n} — works on unseen data (out-of-sample).
 
@@ -911,7 +935,7 @@ def rf_tca_transform(state: RFTCAState, x: jnp.ndarray) -> jnp.ndarray:
     omega = state.omega
     if omega is None:
         omega = fused_transform_omega(state, x.shape[0])
-    return state.w_rf.T @ rff_features(x, omega)
+    return project_features(state.w_rf, rff_features(x, omega))
 
 
 def rf_tca(

@@ -60,7 +60,7 @@ def rff_features(x: jax.Array, omega: jax.Array, *, use_kernel: bool = False) ->
         from repro.kernels import ops as kops
 
         return kops.rff(x, omega)
-    z = omega @ x  # (N, n)
+    z = jnp.matmul(omega, x, precision=jax.lax.Precision.HIGHEST)  # (N, n)
     return jnp.concatenate([jnp.cos(z), jnp.sin(z)], axis=0) / jnp.sqrt(n_features)
 
 

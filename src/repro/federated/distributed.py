@@ -22,21 +22,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.mmd import mmd_projected
 from repro.federated.model import ClientConfig, client_message, source_loss
 from repro.optim import apply_updates
-
-
-def make_client_mesh(n_clients: int) -> Mesh:
-    devs = jax.devices()[:n_clients]
-    if len(devs) < n_clients:
-        raise ValueError(
-            f"need {n_clients} devices for the sharded data plane, have {len(devs)};"
-            " set XLA_FLAGS=--xla_force_host_platform_device_count"
-        )
-    return jax.make_mesh((n_clients,), ("clients",), devices=devs)
 
 
 def build_sharded_round(mesh: Mesh, cfg: ClientConfig, omega: jnp.ndarray, opt):
@@ -92,7 +81,7 @@ def build_sharded_round(mesh: Mesh, cfg: ClientConfig, omega: jnp.ndarray, opt):
         # every stacked opt leaf carries the leading (K,) client axis
         opt_spec = jax.tree_util.tree_map(lambda a: spec_k, stacked_opt)
         param_spec = jax.tree_util.tree_map(lambda _: spec_k, stacked_params)
-        return shard_map(
+        return jax.shard_map(
             per_client,
             mesh=mesh,
             in_specs=(param_spec, opt_spec, spec_k, spec_k, P()),

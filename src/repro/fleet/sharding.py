@@ -29,9 +29,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.extend.core import ClosedJaxpr, Jaxpr
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
+
+from repro.launch.mesh import make_mesh
 
 
 def chunked_vmap(fn, in_axes, *, chunk: int | None):
@@ -102,7 +104,7 @@ def client_mesh(n_shards: int) -> Mesh:
             f"need {n_shards} devices for the clients mesh, have {len(devs)};"
             " set XLA_FLAGS=--xla_force_host_platform_device_count"
         )
-    return jax.make_mesh((n_shards,), ("clients",), devices=devs)
+    return make_mesh((n_shards,), ("clients",), devs)
 
 
 def sharded_client_map(mesh: Mesh, fn, in_axes, *, chunk: int | None = None):
@@ -128,11 +130,28 @@ def sharded_client_map(mesh: Mesh, fn, in_axes, *, chunk: int | None = None):
         )
         out = jax.eval_shape(inner, *args)
         out_specs = jax.tree_util.tree_map(lambda _: P("clients"), out)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs
         )(*args)
 
     return run
+
+
+def eqn_jaxprs(eqn):
+    """The jaxprs an equation carries in its params (``scan``/``map``/
+    ``cond``/``pjit`` bodies, a ``pallas_call`` kernel)."""
+    for v in eqn.params.values():
+        for item in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(item, ClosedJaxpr):
+                yield item.jaxpr
+            elif isinstance(item, Jaxpr):
+                yield item
+
+
+def sub_jaxprs(jaxpr):
+    """Every jaxpr nested one level inside ``jaxpr``'s equations."""
+    for eqn in jaxpr.eqns:
+        yield from eqn_jaxprs(eqn)
 
 
 def working_set_proxy(fn, *args) -> int:
@@ -152,19 +171,6 @@ def working_set_proxy(fn, *args) -> int:
     """
     jaxpr = jax.make_jaxpr(fn)(*args)
 
-    def subjaxprs(params):
-        for v in params.values():
-            if isinstance(v, jax.core.ClosedJaxpr):
-                yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
-                yield v
-            elif isinstance(v, (tuple, list)):
-                for item in v:
-                    if isinstance(item, jax.core.ClosedJaxpr):
-                        yield item.jaxpr
-                    elif isinstance(item, jax.core.Jaxpr):
-                        yield item
-
     data_movement = {
         "reshape", "broadcast_in_dim", "transpose", "squeeze", "expand_dims",
         "concatenate", "pad", "copy", "convert_element_type", "slice",
@@ -174,7 +180,7 @@ def working_set_proxy(fn, *args) -> int:
     def scan_eqns(jx) -> int:
         worst = 0
         for eqn in jx.eqns:
-            subs = list(subjaxprs(eqn.params))
+            subs = list(eqn_jaxprs(eqn))
             if subs:
                 for sub in subs:
                     worst = max(worst, scan_eqns(sub))

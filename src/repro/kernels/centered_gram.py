@@ -40,7 +40,7 @@ def centered_gram_pallas(
     *,
     block: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns (2N, 2N) centered Gram of the RFF matrix."""
     two_n, n = sigma.shape

@@ -75,7 +75,7 @@ def flash_attention_pallas(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, s, d = q.shape
     kv = k.shape[1]

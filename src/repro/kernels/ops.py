@@ -1,8 +1,9 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrappers around the Pallas kernels — the only callers of the
+raw ``*_pallas`` functions, which take no ``interpret`` default.
 
-On CPU (this container) kernels run in interpret mode; on a real TPU pass
-``interpret=False`` (the default flips on TPU backends). Wrappers handle
-padding to tile boundaries so callers keep arbitrary shapes.
+``interpret=None`` (every wrapper's default) resolves from the platform:
+Mosaic-lowered kernels on TPU, interpret mode elsewhere (the CPU tests).
+Wrappers handle padding to tile boundaries so callers keep arbitrary shapes.
 """
 from __future__ import annotations
 
@@ -16,6 +17,8 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.quantize import fake_quant_pallas
 from repro.kernels.rff import rff_fused_pallas, rff_pallas
 from repro.kernels.rff_gram_stream import (
+    DRAW_COLS,
+    VMEM_LIMIT_BYTES,
     rff_gram_stream_fused_pallas,
     rff_gram_stream_fused_tiled_pallas,
     rff_gram_stream_pallas,
@@ -28,45 +31,85 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-# Untiled rff_gram_stream holds 3 (N_pad, N_pad) fp32 accumulators in VMEM;
-# past this N the tiled layout takes over.
+# The untiled layout holds 3 (N_pad, N_pad) fp32 outputs in VMEM; past this N
+# a tiled layout takes over.
 GRAM_TILE_THRESHOLD = 1024
+GRAM_TILES = (512, 256, 128)  # tiled-layout edges, largest first
+# Bytes of VMEM the plan lets one Gram-stream instance's blocks take: three
+# quarters of the scoped limit, the rest left to Mosaic's own temporaries.
+GRAM_VMEM_BUDGET = 3 * VMEM_LIMIT_BYTES // 4
 
 
-def gram_tile_plan(n_features: int, *, tile: int | None = None) -> dict:
-    """Resolve the (tile, VMEM-accumulator-bytes) plan ``rff_gram_stream``
-    will execute for a given feature count.
+def _gram_vmem_bytes(rows: int, dim: int, block: int, *, tiled: bool, fused: bool) -> int:
+    """VMEM one Gram-stream program instance holds, in bytes.
+
+    ``rows`` is the untiled N_pad or the tile edge, ``dim`` the padded p.
+    Blocks whose index moves along the grid (the sample block; the tiled
+    layout's omega and output blocks) are double-buffered; the untiled
+    layout's whole-array omega and outputs are held once.  The fused kernels
+    hold no omega block but a few (rows, DRAW_COLS) draw temporaries per row
+    slab instead.  Checked against the v5e compiler in
+    ``tests/test_tpu_compile.py``.
+    """
+    lanes = lambda c: c + (-c) % 128  # VMEM pads the minor dim to 128 lanes
+    slabs = 2 if tiled else 1  # row slabs whose features one step computes
+    bufs = 2 if tiled else 1
+    if fused:
+        omega = slabs * 8 * rows * DRAW_COLS  # threefry words, draw, phases
+    else:
+        omega = bufs * slabs * rows * lanes(dim)
+    out = bufs * (3 * rows * lanes(rows) + 2 * rows * 128)
+    x_blk = 2 * (dim + 8) * lanes(block)  # sample block + [ell; mask], double-buffered
+    feats = 3 * slabs * rows * lanes(block)  # z, cos, sin per slab
+    return 4 * (omega + out + x_blk + feats)
+
+
+def gram_tile_plan(
+    n_features: int, dim: int, *, tile: int | None = None, fused: bool = False,
+    block: int = 128,
+) -> dict:
+    """Resolve the layout ``rff_gram_stream`` (``fused=True``:
+    ``rff_gram_stream_fused``) will execute for N = ``n_features`` features
+    of p = ``dim``-wide samples.
 
     ``tile=None`` auto-selects: the untiled fast path (``{"tile": None}``)
-    while 3 N_pad^2 fp32 accumulators stay VMEM-friendly (N_pad <=
-    ``GRAM_TILE_THRESHOLD``), else a (t, t) output tiling with t chosen to
-    bound per-instance accumulator memory at 3 t^2 fp32 while keeping the
-    N -> N_pad rounding waste small.  ``tile=0`` forces the untiled path,
-    any other int forces that tile edge — it must be a multiple of 128
-    (TPU lane alignment of the (t, t) blocks; validated here so the mistake
-    cannot pass CPU interpret-mode CI and only surface at Mosaic lowering).
-    Returns ``{"tile", "n_pad", "acc_bytes"}`` — ``acc_bytes`` is the exact
-    per-instance fp32 accumulator footprint, the quantity the VMEM-proxy
-    test bounds.
+    while N_pad <= ``GRAM_TILE_THRESHOLD`` and its VMEM fits
+    ``GRAM_VMEM_BUDGET``, else the largest (t, t) output tile in
+    ``GRAM_TILES`` that keeps the N -> N_pad rounding waste small (256 up to
+    N = 2048) and whose instance — (t, p) omega blocks included — fits the
+    budget.  ``tile=0`` forces the untiled path, any other int forces that
+    tile edge — it must be a multiple of 128 (TPU lane alignment of the
+    (t, t) blocks; validated here so the mistake cannot pass CPU
+    interpret-mode CI and only surface at Mosaic lowering).  Returns
+    ``{"tile", "n_pad", "acc_bytes", "vmem_bytes"}``: ``acc_bytes`` is the
+    per-instance fp32 Gram/moment accumulator footprint, ``vmem_bytes`` the
+    whole instance's estimate the budget bounds.
     """
+    if tile is not None and tile % 128:
+        raise ValueError(f"tile must be a multiple of 128 (TPU lanes), got {tile}")
+    p_pad = dim + (-dim) % (DRAW_COLS if fused else block)
+    n_pad = n_features + (-n_features) % 128
+
+    def vmem(t):
+        rows = n_pad if t is None else t
+        return _gram_vmem_bytes(rows, p_pad, block, tiled=t is not None, fused=fused)
+
     if tile is None:
-        if n_features <= GRAM_TILE_THRESHOLD:
+        if n_pad <= GRAM_TILE_THRESHOLD and vmem(None) <= GRAM_VMEM_BUDGET:
             t = None
         else:
-            # 256 keeps rounding waste <= 12.5% up to 2048; 512 (3 MB of
-            # accumulators) amortizes grid overhead for genuinely large N
-            t = 256 if n_features <= 2048 else 512
+            top = 256 if n_features <= 2048 else 512
+            fits = [c for c in GRAM_TILES if c <= top and vmem(c) <= GRAM_VMEM_BUDGET]
+            if not fits:
+                raise ValueError(f"no Gram tile fits VMEM at p={dim}")
+            t = fits[0]
     else:
-        if tile % 128:
-            raise ValueError(f"tile must be a multiple of 128 (TPU lanes), got {tile}")
         t = tile or None
-    if t is None:
-        n_pad = n_features + (-n_features) % 128
-        acc = 3 * n_pad * n_pad * 4 + 2 * n_pad * 2 * 4
-    else:
+    if t is not None:
         n_pad = n_features + (-n_features) % t
-        acc = 3 * t * t * 4 + 2 * t * 2 * 4
-    return {"tile": t, "n_pad": n_pad, "acc_bytes": acc}
+    rows = n_pad if t is None else t
+    acc = 3 * rows * rows * 4 + 2 * rows * 2 * 4
+    return {"tile": t, "n_pad": n_pad, "acc_bytes": acc, "vmem_bytes": vmem(t)}
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
@@ -139,7 +182,7 @@ def rff_gram_stream(
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     n = x.shape[1]
-    plan_tile = gram_tile_plan(omega.shape[0], tile=tile)["tile"]
+    plan_tile = gram_tile_plan(omega.shape[0], x.shape[0], tile=tile, block=block)["tile"]
     lm = jnp.stack([ell.astype(x.dtype), jnp.ones((n,), x.dtype)])  # (2, n)
     x, _ = _pad_to(x, 1, block)
     lm, _ = _pad_to(lm, 1, block)  # zero-pads ell AND the column mask
@@ -233,11 +276,13 @@ def rff_gram_stream_fused(
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     n = x.shape[1]
-    plan_tile = gram_tile_plan(n_features, tile=tile)["tile"]
+    plan_tile = gram_tile_plan(
+        n_features, x.shape[0], tile=tile, fused=True, block=block
+    )["tile"]
     lm = jnp.stack([ell.astype(x.dtype), jnp.ones((n,), x.dtype)])  # (2, n)
     x, _ = _pad_to(x, 1, block)
     lm, _ = _pad_to(lm, 1, block)  # zero-pads ell AND the column mask
-    x, _ = _pad_to(x, 0, block)
+    x, _ = _pad_to(x, 0, DRAW_COLS)  # the in-kernel draw's column slabs
     if plan_tile is None:
         nf_pad = n_features + (-n_features) % block
         gcc, gcs, gss, mc, ms = rff_gram_stream_fused_pallas(
@@ -263,6 +308,10 @@ def rff_gram_stream_fused(
     )
 
 
+# Payload columns per segment-reduce output block (the kernel tiles D).
+SEGMENT_TILE_D = 2048
+
+
 @functools.partial(jax.jit, static_argnames=("n_segments", "block", "interpret"))
 def segment_reduce(
     values: jax.Array,
@@ -279,7 +328,8 @@ def segment_reduce(
     (K,) -> (n_segments, D) fp32 — the grouped moment merge of the two-tier
     fleet plane as one MXU matmul of the weighted membership matrix against
     the stacked payloads.  Padding: K to the client block (padded rows carry
-    weight 0, so they contribute exact zeros), E and D to the 128 lane edge.
+    weight 0, so they contribute exact zeros), E to the 8-row sublane edge, D
+    to the 128 lane edge and then to whole ``SEGMENT_TILE_D`` column tiles.
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     k, d = values.shape
@@ -290,7 +340,10 @@ def segment_reduce(
     vals, _ = _pad_to(vals, 0, block)
     wm, _ = _pad_to(wm, 0, 8)  # sublane edge of the (E, bk) membership blocks
     vals, _ = _pad_to(vals, 1, block)
-    out = segment_reduce_pallas(wm, vals, block_k=block, interpret=interpret)
+    vals, _ = _pad_to(vals, 1, min(SEGMENT_TILE_D, vals.shape[1]))
+    out = segment_reduce_pallas(
+        wm, vals, block_k=block, block_d=SEGMENT_TILE_D, interpret=interpret
+    )
     return out[:n_segments, :d]
 
 

@@ -66,8 +66,12 @@ def threefry2x32(k0, k1, c0, c1) -> tuple[jax.Array, jax.Array]:
 
 
 def _uniform(bits: jax.Array) -> jax.Array:
-    """uint32 -> fp32 uniform on [0, 1) with 24-bit resolution."""
-    return (bits >> np.uint32(8)).astype(jnp.float32) * jnp.float32(2.0**-24)
+    """uint32 -> fp32 uniform on [0, 1) with 24-bit resolution.
+
+    The 24-bit value goes through int32 on its way to fp32: Mosaic has no
+    uint32 -> fp32 cast, and below 2^24 both conversions are exact.
+    """
+    return (bits >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) * jnp.float32(2.0**-24)
 
 
 def _normal(b0: jax.Array, b1: jax.Array) -> jax.Array:

@@ -41,7 +41,7 @@ def fake_quant_pallas(
     *,
     qmax: int,
     block_r: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     rows, cols = x.shape
     if cols != 128 or rows % block_r:

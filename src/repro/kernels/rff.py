@@ -53,7 +53,7 @@ def rff_pallas(
     block_m: int = 128,
     block_p: int = 128,
     scale_n: int | None = None,  # true N when omega rows are padded
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns Sigma = [cos(Omega X); sin(Omega X)]/sqrt(N) of shape (2N, n)."""
     n_features, p = omega.shape
@@ -126,7 +126,7 @@ def rff_fused_pallas(
     block_n: int = 128,
     block_m: int = 128,
     block_p: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Seed-fused featurize: Sigma = [cos; sin]/sqrt(N) of shape (2*nf_pad, n)
     with the weight blocks drawn inside the kernel.  Weight columns past the

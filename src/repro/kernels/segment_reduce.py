@@ -10,11 +10,11 @@ the weighted membership ``WM = M * w`` against the stacked values, the MXU
 does the segment reduction directly — no scatter, no sort — and the same
 kernel serves every payload kind by flattening trailing dims into D.
 
-Grid: ``(K/bk,)`` with the client-block loop as the only axis; each step
-accumulates ``WM[:, k-block] @ values[k-block, :]`` into an (E_pad, D_pad)
-fp32 VMEM accumulator (edge counts are small — hundreds — so the full output
-fits VMEM comfortably; a (E, D) output tiling along the ``rff_gram_stream``
-tiled layout is the known extension if E*D ever outgrows it).
+Grid: ``(D/td, K/bk)`` with the client-block loop innermost; each step
+accumulates ``WM[:, k-block] @ values[k-block, d-tile]`` into its (E_pad, td)
+fp32 output block, which stays resident in VMEM across the K loop.  Tiling D
+keeps the VMEM per instance at two (bk, td) value blocks whatever the payload
+width — a flattened W_RF at N = 1024, m = 32 is D = 65,536.
 
 ``kernels.ref.segment_reduce_ref`` is the XLA twin (same contraction); the
 fleet merge code uses the twin on non-TPU backends where interpret-mode
@@ -22,28 +22,20 @@ Pallas is slow, exactly like the streaming-Gram solver does.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _segment_reduce_kernel(wm_ref, v_ref, out_ref, acc, *, k_steps: int):
-    k = pl.program_id(0)
-
-    @pl.when(k == 0)
+def _segment_reduce_kernel(wm_ref, v_ref, out_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        acc[...] = jnp.zeros_like(acc)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    acc[...] += jax.lax.dot(
-        wm_ref[...], v_ref[...], preferred_element_type=jnp.float32
+    out_ref[...] += jax.lax.dot(
+        wm_ref[...], v_ref[...], precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-
-    @pl.when(k == k_steps - 1)
-    def _write():
-        out_ref[...] = acc[...]
 
 
 def segment_reduce_pallas(
@@ -51,24 +43,26 @@ def segment_reduce_pallas(
     values: jax.Array,  # (K_pad, D_pad) fp32 stacked client payloads
     *,
     block_k: int = 128,
-    interpret: bool = True,
+    block_d: int,
+    interpret: bool,
 ) -> jax.Array:
     """(E_pad, D_pad) fp32 weighted segment sums; see module docstring."""
     e_pad, k_pad = wm.shape
     k_v, d_pad = values.shape
     bk = min(block_k, k_pad)
-    if k_v != k_pad or k_pad % bk:
-        raise ValueError(f"wm {wm.shape} / values {values.shape} must share K%{bk}==0")
-    k_steps = k_pad // bk
+    td = min(block_d, d_pad)
+    if k_v != k_pad or k_pad % bk or d_pad % td:
+        raise ValueError(
+            f"wm {wm.shape} / values {values.shape} must share K%{bk}==0, D%{td}==0"
+        )
     return pl.pallas_call(
-        functools.partial(_segment_reduce_kernel, k_steps=k_steps),
-        grid=(k_steps,),
+        _segment_reduce_kernel,
+        grid=(d_pad // td, k_pad // bk),
         in_specs=[
-            pl.BlockSpec((e_pad, bk), lambda k: (0, k)),
-            pl.BlockSpec((bk, d_pad), lambda k: (k, 0)),
+            pl.BlockSpec((e_pad, bk), lambda d, k: (0, k)),
+            pl.BlockSpec((bk, td), lambda d, k: (k, d)),
         ],
-        out_specs=pl.BlockSpec((e_pad, d_pad), lambda k: (0, 0)),
+        out_specs=pl.BlockSpec((e_pad, td), lambda d, k: (0, d)),
         out_shape=jax.ShapeDtypeStruct((e_pad, d_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((e_pad, d_pad), jnp.float32)],
         interpret=interpret,
     )(wm, values)

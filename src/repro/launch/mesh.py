@@ -5,16 +5,15 @@ from __future__ import annotations
 import numpy as np
 
 
-def _make_mesh(shape, axes, devices):
-    """jax.make_mesh across jax versions: ``axis_types`` and
-    ``jax.sharding.AxisType`` only exist on newer jax — fall back to a plain
-    mesh (equivalent to all-Auto axes) when they don't."""
-    import jax
+def make_mesh(shape, axes, devices):
+    """The one mesh constructor of the repo: every axis ``AxisType.Auto``.
 
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes, devices=devices)
+    ``jax.make_mesh`` defaults to explicit axes, under which the sharded
+    rounds' gathers raise ``ShardingTypeError``; the programs here let the
+    partitioner place what they do not pin with ``shard_map``."""
+    import jax
+    from jax.sharding import AxisType
+
     return jax.make_mesh(
         shape, axes, devices=devices, axis_types=(AxisType.Auto,) * len(axes)
     )
@@ -34,7 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "Set XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE "
             "importing jax (dryrun.py does this)."
         )
-    return _make_mesh(shape, axes, devs[:n])
+    return make_mesh(shape, axes, devs[:n])
 
 
 def make_host_mesh(model: int = 1):
@@ -44,7 +43,7 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = n // model
-    return _make_mesh((data, model), ("data", "model"), jax.devices()[: data * model])
+    return make_mesh((data, model), ("data", "model"), jax.devices()[: data * model])
 
 
 def data_axis_size(mesh) -> int:

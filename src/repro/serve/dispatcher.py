@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.rf_tca import fused_transform_omega
+from repro.core.rf_tca import fused_transform_omega, project_features
 from repro.core.rff import rff_features
 from repro.federated.protocol import _cycle_pad, _ragged_mask
 from repro.obs import metrics, sentinel
@@ -55,7 +55,7 @@ class Request:
 
 
 def _transform_body(w_rf, omega, x, mask):
-    out = w_rf.T @ rff_features(x, omega)  # (m, bucket)
+    out = project_features(w_rf, rff_features(x, omega))  # (m, bucket)
     return out * mask[None, :]
 
 
@@ -65,13 +65,13 @@ def _transform_probe_body(w_rf, omega, x, mask):
     monitor's live statistic, computed where the features already live (the
     PR-7 probe pattern: auxiliary outputs, primary output unchanged)."""
     feats = rff_features(x, omega)  # (2N, bucket)
-    out = w_rf.T @ feats  # (m, bucket)
+    out = project_features(w_rf, feats)  # (m, bucket)
     moment = (feats * mask[None, :]).sum(axis=1) / jnp.maximum(mask.sum(), 1.0)
     return out * mask[None, :], moment
 
 
 def _predict_body(w_rf, omega, clf_w, clf_b, x, mask):
-    aligned = w_rf.T @ rff_features(x, omega)  # (m, bucket)
+    aligned = project_features(w_rf, rff_features(x, omega))  # (m, bucket)
     logits = clf_w.T @ aligned + clf_b[:, None]  # (C, bucket)
     return logits * mask[None, :]
 

@@ -18,8 +18,9 @@ SCRIPT = textwrap.dedent(
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.federated.distributed import (
-        build_sharded_round, make_client_mesh, stack_clients, unstack_clients,
+        build_sharded_round, stack_clients, unstack_clients,
     )
+    from repro.fleet import client_mesh
     from repro.federated.model import (
         ClientConfig,
         client_message,
@@ -42,7 +43,7 @@ SCRIPT = textwrap.dedent(
     ys = jnp.asarray(rng.integers(0, 3, size=(K, 8)))
     x_t = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
 
-    mesh = make_client_mesh(K)
+    mesh = client_mesh(K)
     rnd = build_sharded_round(mesh, cfg, omega, opt)
     sp = stack_clients(params)
     so = stack_clients(opts)

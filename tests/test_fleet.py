@@ -82,7 +82,9 @@ def test_topology_validation():
 # ---- segment-reduce kernel vs twin -----------------------------------------
 
 
-@pytest.mark.parametrize("k,d,e", [(8, 16, 3), (128, 64, 4), (130, 70, 5), (1, 5, 1)])
+@pytest.mark.parametrize(
+    "k,d,e", [(8, 16, 3), (128, 64, 4), (130, 70, 5), (1, 5, 1), (4, 4100, 2)]
+)
 def test_segment_reduce_kernel_matches_ref(k, d, e):
     rng = np.random.default_rng(k * 7 + d)
     vals = jnp.asarray(rng.normal(size=(k, d)), jnp.float32)

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.fleet.sharding import sub_jaxprs
 from repro.kernels import ops, ref
 
 
@@ -82,27 +83,41 @@ def test_rff_gram_stream_tiled_sweep(p, n, nf, tile):
 
 def test_gram_tile_plan_auto_selection():
     """tile=None keeps the untiled fast path up to the VMEM threshold, then
-    switches to a tile whose accumulator bytes are independent of N."""
-    assert ops.gram_tile_plan(256)["tile"] is None
-    assert ops.gram_tile_plan(ops.GRAM_TILE_THRESHOLD)["tile"] is None
-    t_mid = ops.gram_tile_plan(1300)
-    t_big = ops.gram_tile_plan(8192)
+    switches to a tile whose accumulator bytes are independent of N; at the
+    deployment width p=2048 the (t, p) omega blocks count as well."""
+    assert ops.gram_tile_plan(256, 16)["tile"] is None
+    assert ops.gram_tile_plan(ops.GRAM_TILE_THRESHOLD, 16)["tile"] is None
+    t_mid = ops.gram_tile_plan(1300, 16)
+    t_big = ops.gram_tile_plan(8192, 16)
     assert t_mid["tile"] == 256 and t_mid["n_pad"] % 256 == 0
     assert t_big["tile"] == 512
     # per-instance accumulator memory is set by the tile, not N
     assert t_big["acc_bytes"] == 3 * 512 * 512 * 4 + 2 * 512 * 2 * 4
     assert t_big["acc_bytes"] < 3 * 8192 * 8192 * 4
     # explicit overrides: 0 forces untiled, an int forces that tile edge
-    assert ops.gram_tile_plan(4096, tile=0)["tile"] is None
-    assert ops.gram_tile_plan(300, tile=128)["tile"] == 128
+    assert ops.gram_tile_plan(4096, 16, tile=0)["tile"] is None
+    assert ops.gram_tile_plan(300, 16, tile=128)["tile"] == 128
     # lane-misaligned forced tiles must fail here, not at Mosaic lowering
     with pytest.raises(ValueError, match="multiple of 128"):
-        ops.gram_tile_plan(4096, tile=200)
+        ops.gram_tile_plan(4096, 16, tile=200)
+    # p: every auto layout fits the budget, and a wide enough p shrinks the
+    # untiled layout into tiles and the tiles below the N rule's choice
+    for nf in (512, 1024, 2048, 4096, 8192):
+        for dim in (16, 2048, 8192):
+            for fused in (False, True):
+                plan = ops.gram_tile_plan(nf, dim, fused=fused)
+                assert plan["vmem_bytes"] <= ops.GRAM_VMEM_BUDGET, (nf, dim, fused)
+    assert ops.gram_tile_plan(1024, 2048)["tile"] is None
+    assert ops.gram_tile_plan(1024, 8192)["tile"] == 256
+    wide = ops.gram_tile_plan(8192, 12288)
+    assert wide["tile"] < 512 and wide["vmem_bytes"] <= ops.GRAM_VMEM_BUDGET
+    with pytest.raises(ValueError, match="no Gram tile fits"):
+        ops.gram_tile_plan(4096, 2**20)
 
 
 def test_tiled_kernel_vmem_accumulators_bounded_by_tile():
-    """The pallas_call's scratch accumulators (the VMEM proxy) must be (t, t)
-    blocks, not (N_pad, N_pad) — checked on the traced kernel jaxpr."""
+    """The pallas_call's accumulating output blocks (the VMEM proxy) must be
+    (t, t) blocks, not (N_pad, N_pad) — checked on the traced kernel jaxpr."""
     from repro.core.kernels_math import ell_vector
 
     p, n, nf, tile = 8, 128, 1536, 256
@@ -118,7 +133,7 @@ def test_tiled_kernel_vmem_accumulators_bounded_by_tile():
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
                 yield eqn
-        for sub in jax.core.subjaxprs(jx):
+        for sub in sub_jaxprs(jx):
             yield from find_pallas(sub)
 
     eqns = list(find_pallas(jaxpr.jaxpr))
@@ -307,7 +322,7 @@ def test_fused_path_weightless_jaxpr():
             for v in eqn.outvars:
                 size = int(np.prod(v.aval.shape)) if v.aval.shape else 1
                 assert size <= limit, f"intermediate {v.aval.shape} exceeds fused bound"
-        for sub in jax.core.subjaxprs(jx):
+        for sub in sub_jaxprs(jx):
             walk(sub)
 
     walk(closed.jaxpr)

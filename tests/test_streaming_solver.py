@@ -14,6 +14,7 @@ from repro.core import (
     streaming_gram,
 )
 from repro.core.rff import draw_omega, rff_features
+from repro.fleet.sharding import sub_jaxprs
 
 
 @pytest.fixture(scope="module")
@@ -131,7 +132,7 @@ def test_tiled_kernel_matches_twin_at_n4096():
     x = jax.random.normal(key, (p, n), jnp.float32)
     omega = jax.random.normal(jax.random.fold_in(key, 2), (nf, p), jnp.float32)
     ell = ell_vector(n // 2, n - n // 2)
-    assert kops.gram_tile_plan(nf)["tile"] == 512  # auto-tiled past the ceiling
+    assert kops.gram_tile_plan(nf, p)["tile"] == 512  # auto-tiled past the ceiling
     g_p, u_p = kops.rff_gram_stream(x, omega, ell)  # tile=None -> auto
     g_t, u_t = streaming_gram(x, ell, omega, block=128, tile=512)
     scale = float(jnp.abs(g_t).max())
@@ -159,7 +160,7 @@ def test_tiled_twin_per_pair_memory_bounded_by_tile():
             for v in eqn.outvars:
                 size = int(np.prod(v.aval.shape)) if v.aval.shape else 1
                 assert size <= limit, f"intermediate {v.aval.shape} exceeds tile bound"
-        for sub in jax.core.subjaxprs(jx):
+        for sub in sub_jaxprs(jx):
             walk(sub)
 
     walk(jaxpr.jaxpr)
@@ -191,7 +192,7 @@ def test_streaming_never_materializes_sigma(data):
             for v in eqn.outvars:
                 size = int(np.prod(v.aval.shape)) if v.aval.shape else 1
                 assert size <= limit, f"intermediate {v.aval.shape} exceeds streaming bound"
-        for sub in jax.core.subjaxprs(jx):
+        for sub in sub_jaxprs(jx):
             walk(sub)
 
     walk(jaxpr.jaxpr)
