@@ -1,0 +1,9 @@
+"""Share of the traced window of whole fits in which no operation ran on the
+device: 1 - busy / window."""
+UNIT = "%"
+
+
+def read(ctx):
+    if ctx.trace is None or "fits" not in ctx.record or ctx.trace["window_ns"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace["busy_ns"] / ctx.trace["window_ns"])
