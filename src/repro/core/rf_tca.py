@@ -56,6 +56,7 @@ from repro.core.kernels_math import (
     ell_vector,
 )
 from repro.core.rff import draw_omega, rff_features
+from repro.obs.tracing import span
 
 
 class RFTCAState(NamedTuple):
@@ -546,12 +547,22 @@ def _top_eigh(cmat, m: int):
     has finished — running it as an in-program callback stalls it badly
     (XLA's spin-waiting worker threads starve the single-threaded LAPACK
     call).  Under tracing it becomes a ``pure_callback``.
+
+    On concrete arrays each step is a program span (``repro.obs.span``): the
+    wait for the program that made ``cmat``, its copy to the host, the
+    eigensolve, and the upload of the m pairs.
     """
     if not isinstance(cmat, jax.core.Tracer):
         import numpy as np
 
-        vals, vecs = _host_top_eigh(np.asarray(cmat), m=m)
-        return jnp.asarray(vals), jnp.asarray(vecs)
+        with span("rf_tca.stats_wait"):
+            jax.block_until_ready(cmat)
+        with span("rf_tca.cmat_to_host", bytes=int(cmat.nbytes)):
+            host = np.asarray(cmat)
+        with span("rf_tca.eigh", two_n=int(host.shape[0]), m=int(m)):
+            vals, vecs = _host_top_eigh(host, m=m)
+        with span("rf_tca.vecs_to_device", bytes=int(vals.nbytes + vecs.nbytes)):
+            return jnp.asarray(vals), jnp.asarray(vecs)
     out_shapes = (
         jax.ShapeDtypeStruct((m,), jnp.float32),
         jax.ShapeDtypeStruct((cmat.shape[0], m), jnp.float32),
@@ -815,56 +826,61 @@ def rf_tca_fit(
     ``ensemble=S`` then averages the (G_H, u) statistics over S
     independently-keyed draws in the same pass (S=1 is bitwise the
     single-draw path); out-of-sample transforms use draw 0's feature map.
+
+    The call is the program span ``rf_tca.fit`` (``repro.obs.span``).
     """
-    if mode not in ("stream", "dense"):
-        raise ValueError(f"unknown mode {mode!r}")
-    if solver not in ("eigh", "lobpcg", "cholesky"):
-        raise ValueError(f"unknown solver {solver!r}")
-    if mode == "stream" and solver == "cholesky":
-        raise ValueError(
-            'solver="cholesky" factorizes the explicit-Sigma path and requires '
-            'mode="dense"; the streaming solvers are "eigh" and "lobpcg"'
-        )
-    fused_seed = _parse_fused_spec(w_rf)
-    if ensemble != 1 and fused_seed is None:
-        raise ValueError('ensemble > 1 requires w_rf="fused:<seed>"')
-    if fused_seed is not None:
-        if mode != "stream":
-            raise ValueError('w_rf="fused:<seed>" requires mode="stream"')
-        state, _ = _fit_fused(
-            x_s, x_t, n_features=n_features, m=m, gamma=gamma, sigma=sigma,
-            seed=seed, kernel=kernel, use_pallas=use_pallas, solver=solver,
-            fused_seed=fused_seed, ensemble=ensemble,
-        )
-        return state
-    if mode == "stream" and not use_pallas:
-        key = jax.random.PRNGKey(seed)
-        blk = min(block, x_s.shape[1] + x_t.shape[1])
-        if solver == "lobpcg":
-            omega, w_rf, vals = _fit_stream_lobpcg(
-                x_s, x_t, key, gamma, sigma,
-                n_features=n_features, m=m, block=blk, kernel=kernel,
-                lobpcg_iters=100, lobpcg_tol=None,
+    n = int(x_s.shape[1] + x_t.shape[1])
+    with span("rf_tca.fit", n=n, p=int(x_s.shape[0]), n_features=int(n_features),
+              m=int(m)):
+        if mode not in ("stream", "dense"):
+            raise ValueError(f"unknown mode {mode!r}")
+        if solver not in ("eigh", "lobpcg", "cholesky"):
+            raise ValueError(f"unknown solver {solver!r}")
+        if mode == "stream" and solver == "cholesky":
+            raise ValueError(
+                'solver="cholesky" factorizes the explicit-Sigma path and requires '
+                'mode="dense"; the streaming solvers are "eigh" and "lobpcg"'
             )
+        fused_seed = _parse_fused_spec(w_rf)
+        if ensemble != 1 and fused_seed is None:
+            raise ValueError('ensemble > 1 requires w_rf="fused:<seed>"')
+        if fused_seed is not None:
+            if mode != "stream":
+                raise ValueError('w_rf="fused:<seed>" requires mode="stream"')
+            state, _ = _fit_fused(
+                x_s, x_t, n_features=n_features, m=m, gamma=gamma, sigma=sigma,
+                seed=seed, kernel=kernel, use_pallas=use_pallas, solver=solver,
+                fused_seed=fused_seed, ensemble=ensemble,
+            )
+            return state
+        if mode == "stream" and not use_pallas:
+            key = jax.random.PRNGKey(seed)
+            blk = min(block, n)
+            if solver == "lobpcg":
+                omega, w_rf, vals = _fit_stream_lobpcg(
+                    x_s, x_t, key, gamma, sigma,
+                    n_features=n_features, m=m, block=blk, kernel=kernel,
+                    lobpcg_iters=100, lobpcg_tol=None,
+                )
+            else:
+                omega, cmat, u = _fit_stream_stats(
+                    x_s, x_t, key, gamma, sigma,
+                    n_features=n_features, block=blk, kernel=kernel,
+                )
+                vals, vecs = _top_eigh(cmat, m)
+                w_rf = _apply_whiten(u, gamma, vecs)
+            return RFTCAState(omega=omega, w_rf=w_rf, eigvals=vals)
+        p = x_s.shape[0]
+        omega = draw_omega(seed, n_features, p, sigma=sigma, kernel=kernel)
+        x = jnp.concatenate([x_s, x_t], axis=1)
+        ell = ell_vector(x_s.shape[1], x_t.shape[1])
+        if mode == "stream":
+            g_h, u = streaming_gram(x, ell, omega, block=block, use_pallas=use_pallas)
+            w_rf, vals = solve_w_rf_gram(g_h, u, gamma, m, solver=solver, seed=seed)
         else:
-            omega, cmat, u = _fit_stream_stats(
-                x_s, x_t, key, gamma, sigma,
-                n_features=n_features, block=blk, kernel=kernel,
-            )
-            vals, vecs = _top_eigh(cmat, m)
-            w_rf = _apply_whiten(u, gamma, vecs)
+            sig = rff_features(x, omega, use_kernel=use_pallas)
+            w_rf, vals = solve_w_rf(sig, ell, gamma, m, use_kernel=use_pallas, solver=solver)
         return RFTCAState(omega=omega, w_rf=w_rf, eigvals=vals)
-    p = x_s.shape[0]
-    omega = draw_omega(seed, n_features, p, sigma=sigma, kernel=kernel)
-    x = jnp.concatenate([x_s, x_t], axis=1)
-    ell = ell_vector(x_s.shape[1], x_t.shape[1])
-    if mode == "stream":
-        g_h, u = streaming_gram(x, ell, omega, block=block, use_pallas=use_pallas)
-        w_rf, vals = solve_w_rf_gram(g_h, u, gamma, m, solver=solver, seed=seed)
-    else:
-        sig = rff_features(x, omega, use_kernel=use_pallas)
-        w_rf, vals = solve_w_rf(sig, ell, gamma, m, use_kernel=use_pallas, solver=solver)
-    return RFTCAState(omega=omega, w_rf=w_rf, eigvals=vals)
 
 
 # Fused-path transform omega memo: the draw is a pure function of the spec

@@ -5,7 +5,8 @@ One spine for the stack's observability (see each submodule's docstring):
 - :mod:`repro.obs.registry` — labeled counters/gauges/histograms with a
   no-op default (telemetry off costs one attribute lookup + empty call).
 - :mod:`repro.obs.tracing` — virtual/wall-clock spans exported as Chrome
-  trace-event JSON (Perfetto-viewable).
+  trace-event JSON (Perfetto-viewable), and the program spans written into
+  the JAX profiler's trace (``span``, ``SPAN_NAMES``).
 - :mod:`repro.obs.sentinel` — jit retrace counters per compiled plane.
 - :mod:`repro.obs.records` — typed history/ledger records with dict views.
 - :mod:`repro.obs.probes` — host-side emission of in-graph health probes.
@@ -41,10 +42,12 @@ from repro.obs.slo import Slo, SloEngine, SloViolation, quarantine_slo
 from repro.obs.tracing import (
     PID_VIRTUAL,
     PID_WALL,
+    SPAN_NAMES,
     Tracer,
     count_request_trees,
     get_tracer,
     set_tracer,
+    span,
     use_tracer,
     validate_trace,
     validate_trace_file,
@@ -58,6 +61,7 @@ __all__ = [
     "NULL",
     "PID_VIRTUAL",
     "PID_WALL",
+    "SPAN_NAMES",
     "CommRecord",
     "Counter",
     "CrashRecord",
@@ -87,6 +91,7 @@ __all__ = [
     "sentinel",
     "set_registry",
     "set_tracer",
+    "span",
     "use_registry",
     "use_tracer",
     "validate_trace",

@@ -30,12 +30,22 @@ format requires):
 Determinism: a tracer fed only virtual-time events from the deterministic
 fedsim event loop serializes to byte-identical JSON across runs — the
 trace-determinism test pins that.
+
+Program spans: :func:`span` marks a step of the program (the fit's
+eigensolve, the dispatcher's legs) as a ``jax.profiler.TraceAnnotation``,
+which lands in the JAX profiler's trace on the device trace's clock with its
+keyword arguments as event stats, and costs about a microsecond when no
+profiler session is active.  With a :class:`Tracer` installed it also
+records the wall-clock ``B``/``E`` pair of :meth:`Tracer.span`.  Every such
+name is in :data:`SPAN_NAMES`.
 """
 from __future__ import annotations
 
 import contextlib
 import json
 import time
+
+from jax.profiler import TraceAnnotation
 
 PID_WALL = 1
 PID_VIRTUAL = 2
@@ -149,6 +159,48 @@ def use_tracer(tracer: Tracer | None = None):
         yield t
     finally:
         set_tracer(prev)
+
+
+# -- program spans (the profiler's trace, and the installed tracer) ----------
+
+# every name :func:`span` is called with, leaves under their parents:
+# rf_tca.fit > stats_wait, cmat_to_host, eigh, vecs_to_device (core/rf_tca);
+# serve.call > batch_assembly, padded_dispatch > launch, device_wait, fetch
+# (serve/server, serve/dispatcher)
+SPAN_NAMES = (
+    "rf_tca.fit",
+    "rf_tca.stats_wait",
+    "rf_tca.cmat_to_host",
+    "rf_tca.eigh",
+    "rf_tca.vecs_to_device",
+    "serve.call",
+    "serve.batch_assembly",
+    "serve.padded_dispatch",
+    "serve.launch",
+    "serve.device_wait",
+    "serve.fetch",
+)
+
+
+def span(name: str, **args):
+    """Context manager marking one step of the program.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **args)``: recorded, with
+    ``args`` as its stats, only while a profiler session is active.  With a
+    :class:`Tracer` installed, also its wall-clock ``B``/``E`` pair (``args``
+    on the ``B`` event).  Call it from Python only, never inside a traced
+    function: there it would mark the trace, not the run.
+    """
+    tracer = _TRACER
+    if tracer is None:
+        return TraceAnnotation(name, **args)
+    return _span_twin(tracer, name, args)
+
+
+@contextlib.contextmanager
+def _span_twin(tracer: Tracer, name: str, args: dict):
+    with TraceAnnotation(name, **args), tracer.span(name, args=args or None):
+        yield
 
 
 # -- schema validation (the CI bench-smoke contract) --------------------------

@@ -18,12 +18,16 @@ closed set of shapes.
   real samples (never zeros) and ``_ragged_mask`` marks the valid columns;
   the compiled body multiplies its output by the mask, so pad columns leave
   the dispatch as exact zeros and per-request slices are taken host-side.
-- **Telemetry.**  Batch sizes (requests and valid columns per dispatch) land
-  in the metrics registry and in host-side counters for the bench record.
-  None of it touches array values — telemetry off is bitwise identical.
+- **Telemetry.**  Requests and dispatches are counted in the metrics
+  registry, batch sizes in host-side counters for the bench record
+  (:meth:`BatchingDispatcher.histogram`), and each dispatch's legs are
+  program spans (``repro.obs.span``) carrying its requests, valid columns and
+  bucket.  None of it touches array values — telemetry off is bitwise
+  identical.
 """
 from __future__ import annotations
 
+import collections
 import time
 from dataclasses import dataclass
 from typing import Any
@@ -35,7 +39,7 @@ import numpy as np
 from repro.core.rf_tca import fused_transform_omega, project_features
 from repro.core.rff import rff_features
 from repro.federated.protocol import _cycle_pad, _ragged_mask
-from repro.obs import metrics, sentinel
+from repro.obs import metrics, sentinel, span
 
 
 @dataclass
@@ -76,6 +80,10 @@ def _predict_body(w_rf, omega, clf_w, clf_b, x, mask):
     return logits * mask[None, :]
 
 
+# the leg log's bound: a server whose caller never drains it keeps this many
+LEG_LOG_MAX = 65536
+
+
 class BatchingDispatcher:
     """Coalesces queued requests into bucketed compiled dispatches."""
 
@@ -100,7 +108,10 @@ class BatchingDispatcher:
         # drift wiring: when set, transform dispatches run the probed plane
         # and hand (domain_pair, batch moment, n_valid_cols) to this callable
         self.moment_hook = None
-        self._leg_log: list[tuple[float, float]] = []  # (assemble_s, dispatch_s)
+        # (assemble_s, dispatch_s) per dispatch, the newest LEG_LOG_MAX kept
+        self._leg_log: collections.deque[tuple[float, float]] = collections.deque(
+            maxlen=LEG_LOG_MAX
+        )
 
     def bucket_for(self, n_cols: int) -> int:
         """Smallest power-of-two rung >= n_cols (clamped to the ladder)."""
@@ -126,9 +137,7 @@ class BatchingDispatcher:
 
     def submit(self, req: Request) -> None:
         self.pending.append(req)
-        reg = metrics()
-        reg.counter("serve.requests").inc(mode=req.mode)
-        reg.gauge("serve.queue_depth").set(len(self.pending))
+        metrics().counter("serve.requests").inc(mode=req.mode)
 
     def _take_batch(self) -> list[Request]:
         """Pop a head-of-line run of same-mode requests filling <= max_bucket
@@ -150,50 +159,58 @@ class BatchingDispatcher:
         return batch
 
     def _dispatch(self, entry, batch: list[Request]) -> list[np.ndarray]:
-        """One compiled call over the batch's concatenated columns."""
+        """One compiled call over the batch's concatenated columns.
+
+        Its two legs are the spans ``serve.batch_assembly`` (concatenation,
+        padding, mask, omega) and ``serve.padded_dispatch``, split into
+        ``serve.launch`` (the plane's call, argument upload included),
+        ``serve.device_wait`` and ``serve.fetch`` (the copy to the host).
+        """
         t0 = time.perf_counter()
         state = entry.state
-        x = np.concatenate([np.asarray(r.x, np.float32) for r in batch], axis=1)
-        n_cols = x.shape[1]
+        n_cols = sum(int(np.shape(r.x)[1]) for r in batch)
         bucket = self.bucket_for(n_cols)
-        x_pad, _ = _cycle_pad(x, None, bucket)
-        mask_rows = _ragged_mask([n_cols], bucket)
-        mask = (
-            np.ones((bucket,), np.float32)
-            if mask_rows is None
-            else np.asarray(mask_rows[0])
-        )
-        omega = state.omega
-        if omega is None:
-            omega = fused_transform_omega(state, x.shape[0])
         mode = batch[0].mode
+        with span("serve.batch_assembly", requests=len(batch), cols=n_cols, bucket=bucket):
+            x = np.concatenate([np.asarray(r.x, np.float32) for r in batch], axis=1)
+            x_pad, _ = _cycle_pad(x, None, bucket)
+            mask_rows = _ragged_mask([n_cols], bucket)
+            mask = (
+                np.ones((bucket,), np.float32)
+                if mask_rows is None
+                else np.asarray(mask_rows[0])
+            )
+            omega = state.omega
+            if omega is None:
+                omega = fused_transform_omega(state, x.shape[0])
         probe = self.moment_hook is not None and mode == "transform"
         t1 = time.perf_counter()
         moment = None
-        if mode == "predict":
-            if entry.classifier is None:
-                raise ValueError("predict request against an entry with no classifier")
-            out = self._plane(mode, bucket)(
-                state.w_rf, omega, entry.classifier["w"], entry.classifier["b"],
-                x_pad, mask,
-            )
-        elif probe:
-            out, moment = self._plane(mode, bucket, probe=True)(
-                state.w_rf, omega, x_pad, mask
-            )
-        else:
-            out = self._plane(mode, bucket)(state.w_rf, omega, x_pad, mask)
-        out = np.asarray(jax.block_until_ready(out))
+        with span("serve.padded_dispatch"):
+            with span("serve.launch"):
+                if mode == "predict":
+                    if entry.classifier is None:
+                        raise ValueError("predict request against an entry with no classifier")
+                    out = self._plane(mode, bucket)(
+                        state.w_rf, omega, entry.classifier["w"], entry.classifier["b"],
+                        x_pad, mask,
+                    )
+                elif probe:
+                    out, moment = self._plane(mode, bucket, probe=True)(
+                        state.w_rf, omega, x_pad, mask
+                    )
+                else:
+                    out = self._plane(mode, bucket)(state.w_rf, omega, x_pad, mask)
+            with span("serve.device_wait"):
+                out.block_until_ready()
+            with span("serve.fetch"):
+                out = np.asarray(out)
         t2 = time.perf_counter()
         self._leg_log.append((t1 - t0, t2 - t1))
         self.dispatches += 1
         self.batch_requests[len(batch)] = self.batch_requests.get(len(batch), 0) + 1
         self.batch_columns[bucket] = self.batch_columns.get(bucket, 0) + 1
-        reg = metrics()
-        reg.counter("serve.dispatches").inc(mode=mode, bucket=bucket)
-        reg.histogram("serve.batch_requests").observe(len(batch))
-        reg.histogram("serve.batch_fill").observe(n_cols / bucket)
-        reg.histogram("serve.dispatch_s").observe(t2 - t1, bucket=bucket)
+        metrics().counter("serve.dispatches").inc(mode=mode, bucket=bucket)
         if moment is not None:
             self.moment_hook(batch[0].key, np.asarray(moment), n_cols)
         results, off = [], 0
@@ -205,8 +222,10 @@ class BatchingDispatcher:
 
     def take_legs(self) -> list[tuple[float, float]]:
         """Drain the wall-clock ``(assemble_s, dispatch_s)`` pairs logged
-        since the last call — the request tracer's processing-leg split."""
-        legs, self._leg_log = self._leg_log, []
+        since the last call (at most the newest ``LEG_LOG_MAX``) — the request
+        tracer's processing-leg split."""
+        legs = list(self._leg_log)
+        self._leg_log.clear()
         return legs
 
     def flush(self, entry) -> list[tuple[Request, np.ndarray]]:

@@ -38,7 +38,7 @@ from repro.core.rf_tca import (
     rf_tca_resolve,
 )
 from repro.core.rff import rff_features
-from repro.obs import metrics
+from repro.obs import metrics, span
 from repro.serve.admission import AdmissionGateway, AdmissionResult, admission_message, client_moment
 from repro.serve.dispatcher import BatchingDispatcher, Request
 from repro.serve.store import ModelStore, StoreEntry
@@ -153,18 +153,20 @@ class AlignerServer:
         return entry
 
     def serve(self, requests: list[Request]) -> list[tuple[Request, np.ndarray]]:
-        """Dispatch a burst of requests; same-key runs batch together."""
+        """Dispatch a burst of requests; same-key runs batch together.  The
+        call is the program span ``serve.call``."""
         done: list[tuple[Request, np.ndarray]] = []
-        i = 0
-        while i < len(requests):
-            key = requests[i].key
-            j = i
-            while j < len(requests) and requests[j].key == key:
-                self.dispatcher.submit(requests[j])
-                j += 1
-            entry = self.get_or_fit(key)
-            done.extend(self.dispatcher.flush(entry))
-            i = j
+        with span("serve.call", requests=len(requests)):
+            i = 0
+            while i < len(requests):
+                key = requests[i].key
+                j = i
+                while j < len(requests) and requests[j].key == key:
+                    self.dispatcher.submit(requests[j])
+                    j += 1
+                entry = self.get_or_fit(key)
+                done.extend(self.dispatcher.flush(entry))
+                i = j
         return done
 
     def warmup(self, domain_pair, *, modes: tuple[str, ...] = ("transform",)) -> int:
