@@ -16,8 +16,9 @@ Phases on one chip, at p = 2048 (the width of pooled ResNet-50 features):
 - fit: ``rf_tca_fit`` with n = 16384 samples per domain, N in {1024, 4096}
   and m = 32, with materialized omega and with ``w_rf="fused:<seed>"``, both
   ``use_pallas=True``.  Each Pallas statistics pass lowers to
-  ``tpu_custom_call`` and agrees with its XLA twin at rel <= 1e-4; the host
-  eigensolve also runs under jit (as a ``pure_callback``).
+  ``tpu_custom_call`` and agrees with its XLA twin at rel <= 1e-4; the
+  eigensolve, on the device when called eagerly, also runs under jit (on the
+  host, as a ``pure_callback``) and the two agree.
 - rounds: ``FedRFTCATrainer`` (batched engine) on an Office-31-shaped
   federation (31 classes, 4 sources and 1 target) with N = 1024, m = 32, a
   two-edge topology and the qint8 codec, so ``segment_reduce`` and
@@ -141,7 +142,7 @@ def phase_fit(x_s, x_t) -> dict:
                 flush=True,
             )
             out[f"{name}_{nf}"] = {"rel_G": rel_g, "rel_u": rel_u}
-        if nf == FIT_FEATURES[0]:  # the host eigensolve as a pure_callback under jit
+        if nf == FIT_FEATURES[0]:  # eager (device) vs jit (host pure_callback) eigensolve
             t0 = time.perf_counter()
             solve = functools.partial(solve_w_rf_gram, gamma=1.0, m=M)
             w_eager, v_eager = solve(g_x, u_x)

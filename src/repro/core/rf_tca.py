@@ -27,15 +27,21 @@ which replaces the Cholesky factorization + two triangular solves with two
 rank-one updates (O(N^2) instead of O(N^3)).  The whitened operator
 C = B^{-1/2} G_H B^{-1/2} is then diagonalized by:
 
-- ``solver="eigh"``   — host SciPy symmetric eigendecomposition of the top-m
-  eigenpairs only (LAPACK ``syevr`` subset — much cheaper than a full
-  ``eigh``).  Best up to 2N ~ a few thousand; bitwise-deterministic.
+- ``solver="eigh"``   — the top-m eigenpairs of C, the path chosen by shape.
+  When the matrix is large next to m (2N >= 16m), blocked subspace
+  iteration with k = 2m columns runs on the device where C already lives:
+  products C·X at ``HIGHEST`` with a QR after each, and every few products
+  a Rayleigh–Ritz step whose only host work is a float64 ``eigh`` of the
+  k x k matrix X^T C X.  It stops when the top-m residuals fall under a
+  relative tolerance; 2N-sized data never leaves the device.  Smaller
+  matrices, and a solve that reaches the iteration cap unconverged, go to
+  host SciPy ``syevr`` on the top-m subset (a fallback costs time, never
+  accuracy).  Deterministic for its inputs on either path.
 - ``solver="lobpcg"`` — matrix-free top-m LOBPCG
-  (``jax.experimental.sparse.linalg.lobpcg_standard``) that only applies
-  C·v products (O(N^2 m) per iteration).  Pick this when 2N is large enough
-  that an O((2N)^3) factorization dominates (2N >~ 4096) or on accelerators
-  where the full eigh does not parallelize.  Falls back to ``eigh`` when
-  5m >= 2N (the LOBPCG search block would not fit).
+  (``jax.experimental.sparse.linalg.lobpcg_standard``) inside one compiled
+  program, with no host work at all.  Its search block is m itself, so it
+  converges slowly where the spectrum is flat past the m-th pair.  Falls
+  back to ``eigh`` (as an in-program host callback) when 5m >= 2N.
 - ``solver="cholesky"`` — the original Cholesky-whitening + full ``eigh``
   reference path (seed implementation), kept for benchmarking.
 
@@ -49,6 +55,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.kernels_math import (
     assemble_streamed_gram,
@@ -56,6 +63,7 @@ from repro.core.kernels_math import (
     ell_vector,
 )
 from repro.core.rff import draw_omega, rff_features
+from repro.obs.registry import get_registry as metrics
 from repro.obs.tracing import span
 
 
@@ -525,7 +533,6 @@ _lobpcg_solve = functools.partial(
 
 def _host_top_eigh(cmat, *, m: int):
     """Host-side LAPACK subset eigendecomposition (syevr): top-m pairs only."""
-    import numpy as np
     from scipy.linalg import eigh
 
     two_n = cmat.shape[0]
@@ -538,28 +545,125 @@ def _host_top_eigh(cmat, *, m: int):
     )
 
 
+# The device eigensolve: blocked subspace iteration on C where it lives.
+EIGH_BLOCK = 2  # search block k = EIGH_BLOCK * m columns (2m: fastest of 2m-4m on a v5e)
+EIGH_CHECK_EVERY = 10  # products C·X between two Rayleigh–Ritz checks
+EIGH_MAX_PRODUCTS = 300  # past this unconverged, the host solve takes over
+EIGH_RTOL = 2e-6  # largest ||C v - theta v|| / theta of the top m Ritz pairs
+
+
+def _device_eigh_fits(two_n: int, m: int) -> bool:
+    """The shape rule of ``_top_eigh``: subspace iteration wants a block of
+    2m to 4m columns well inside the matrix, at most a quarter of it
+    (2N >= 16m); below that the copy and LAPACK cost little."""
+    return two_n >= 16 * m
+
+
+@functools.partial(jax.jit, static_argnames=("two_n", "k"))
+def _start_block(two_n: int, k: int):
+    """The iteration's Gaussian start, from a fixed key (deterministic fits)."""
+    return jax.random.normal(jax.random.PRNGKey(0), (two_n, k), jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("steps",))
+def _subspace_steps(cmat, y, *, steps: int):
+    """``steps`` products x <- qr(y), y <- C x, then the Rayleigh–Ritz data
+    of the last (x, y): H = x^T C x and the Gram R^T R of
+    R = y - x H = (I - x x^T) C x, stacked as (2, k, k).
+
+    For a Ritz pair (theta, s) of H, C x s - theta x s = R s, so the host
+    reads every residual norm as sqrt(s^T R^T R s) from the same 2 k^2
+    numbers, with no second trip to the device."""
+
+    def body(_, xy):
+        x = jnp.linalg.qr(xy[1])[0]
+        return x, _mm(cmat, x)
+
+    x, y = jax.lax.fori_loop(0, steps, body, (y, y))
+    h = _mm(x.T, y)
+    h = 0.5 * (h + h.T)
+    r = y - _mm(x, h)
+    return y, x, jnp.stack([h, _mm(r.T, r)])
+
+
+@jax.jit
+def _ritz_vectors(x, s):
+    return _mm(x, s)
+
+
+def _device_top_eigh(cmat, m: int):
+    """Top-m (vals desc, vecs) of the symmetric PSD ``cmat``, on its device.
+
+    Blocked subspace iteration with k = EIGH_BLOCK * m columns: the m-th
+    pair converges at lambda_{k+1} / lambda_m per product, so a block well
+    past m gets through a flat stretch of the spectrum.  Every
+    EIGH_CHECK_EVERY products the k x k H = X^T C X and the Gram of its
+    residual come to the host (2 k^2 float32 words); NumPy solves H in
+    float64, the only step that needs more than float32, and the solve
+    stops when every top-m pair has ||C v - theta v|| <= EIGH_RTOL theta.
+    The k x m Ritz coefficients then go back and the device forms X S.
+
+    Returns (vals, vecs, products); vals and vecs are None when
+    EIGH_MAX_PRODUCTS products did not converge.
+    """
+    two_n = int(cmat.shape[0])
+    y = _start_block(two_n, EIGH_BLOCK * m)
+    products = 0
+    while products < EIGH_MAX_PRODUCTS:
+        steps = min(EIGH_CHECK_EVERY, EIGH_MAX_PRODUCTS - products)
+        y, x, ritz = _subspace_steps(cmat, y, steps=steps)
+        products += steps
+        h, rr = np.asarray(ritz, np.float64)
+        theta, s = np.linalg.eigh(h)
+        theta, s = theta[::-1][:m], s[:, ::-1][:, :m]
+        resid = np.sqrt(np.maximum(np.einsum("ij,ik,kj->j", s, rr, s), 0.0))
+        if np.all(resid <= EIGH_RTOL * theta):
+            vecs = _ritz_vectors(x, jnp.asarray(s, jnp.float32))
+            return jnp.asarray(theta, jnp.float32), vecs, products
+    return None, None, products
+
+
 def _top_eigh(cmat, m: int):
-    """Top-m (vals desc, vecs) of a symmetric matrix, on the host.
+    """Top-m (vals desc, vecs) of a symmetric matrix.
 
-    The LAPACK subset driver (syevr) only back-transforms the m requested
-    eigenvectors and is several times faster than a full ``eigh`` at bench
-    sizes.  On concrete arrays SciPy is called directly AFTER the XLA program
-    has finished — running it as an in-program callback stalls it badly
-    (XLA's spin-waiting worker threads starve the single-threaded LAPACK
-    call).  Under tracing it becomes a ``pure_callback``.
+    On concrete arrays the path follows the shape alone.  When 2N >= 16m
+    (``_device_eigh_fits``) ``_device_top_eigh`` solves on the device that
+    holds ``cmat``; only k x k matrices cross to the host, for its float64
+    Rayleigh–Ritz step.  Smaller matrices, and a device solve that reaches
+    EIGH_MAX_PRODUCTS unconverged, take the host path: ``cmat`` is copied
+    to the host once the program that made it has finished and the LAPACK
+    subset driver (syevr) back-transforms only the m requested vectors.
+    SciPy is called outside the program because an in-program callback
+    stalls it badly (XLA's spin-waiting worker threads starve the
+    single-threaded LAPACK call).  Under tracing the host solve becomes a
+    ``pure_callback``.
 
-    On concrete arrays each step is a program span (``repro.obs.span``): the
-    wait for the program that made ``cmat``, its copy to the host, the
-    eigensolve, and the upload of the m pairs.
+    On concrete arrays each step is a program span (``repro.obs.span``):
+    ``rf_tca.stats_wait`` (the wait for the program that made ``cmat``) and
+    ``rf_tca.eigh`` (args ``two_n``, ``m``, ``path``; on the device also
+    ``block`` and ``iters``, the products it took), with the host path's
+    copy ``rf_tca.cmat_to_host`` before its eigh and upload
+    ``rf_tca.vecs_to_device`` after.  A fallback shows the device attempt
+    and the host solve as two ``rf_tca.eigh`` spans.  The registry counter
+    ``rf_tca.eigh_solves`` counts solves by ``path`` ("device", "host",
+    "host_fallback").
     """
     if not isinstance(cmat, jax.core.Tracer):
-        import numpy as np
-
         with span("rf_tca.stats_wait"):
             jax.block_until_ready(cmat)
+        two_n = int(cmat.shape[0])
+        path = "host"
+        if _device_eigh_fits(two_n, m):
+            with span("rf_tca.eigh", two_n=two_n, m=int(m), block=EIGH_BLOCK * m) as sp:
+                vals, vecs, iters = _device_top_eigh(cmat, m)
+                path = "host_fallback" if vals is None else "device"
+                sp.set_metadata(iters=iters, path=path)
+        metrics().counter("rf_tca.eigh_solves").inc(path=path)
+        if path == "device":
+            return vals, vecs
         with span("rf_tca.cmat_to_host", bytes=int(cmat.nbytes)):
             host = np.asarray(cmat)
-        with span("rf_tca.eigh", two_n=int(host.shape[0]), m=int(m)):
+        with span("rf_tca.eigh", two_n=two_n, m=int(m), path=path):
             vals, vecs = _host_top_eigh(host, m=m)
         with span("rf_tca.vecs_to_device", bytes=int(vals.nbytes + vecs.nbytes)):
             return jnp.asarray(vals), jnp.asarray(vecs)
@@ -668,8 +772,7 @@ def _fit_stream_stats(
 ):
     """Streamed statistics as ONE compiled program: omega draw, blocked Gram
     scan and Sherman–Morrison whitening fuse into (omega, C, u).  The top-m
-    eigensolve runs on the host afterwards (see _top_eigh for why it must not
-    be an in-program callback)."""
+    eigensolve follows in ``_top_eigh``, on C where it lies."""
     omega = _draw_omega_traced(key, x_s.shape[0], sigma, n_features=n_features, kernel=kernel)
     x = jnp.concatenate([x_s, x_t], axis=1)
     ell = ell_vector(x_s.shape[1], x_t.shape[1])

@@ -36,8 +36,9 @@ eigensolve, the dispatcher's legs) as a ``jax.profiler.TraceAnnotation``,
 which lands in the JAX profiler's trace on the device trace's clock with its
 keyword arguments as event stats, and costs about a microsecond when no
 profiler session is active.  With a :class:`Tracer` installed it also
-records the wall-clock ``B``/``E`` pair of :meth:`Tracer.span`.  Every such
-name is in :data:`SPAN_NAMES`.
+records the wall-clock ``B``/``E`` pair of :meth:`Tracer.span`.  Args known
+only at the end go in through ``set_metadata`` on what the ``with`` gives.
+Every such name is in :data:`SPAN_NAMES`.
 """
 from __future__ import annotations
 
@@ -164,7 +165,8 @@ def use_tracer(tracer: Tracer | None = None):
 # -- program spans (the profiler's trace, and the installed tracer) ----------
 
 # every name :func:`span` is called with, leaves under their parents:
-# rf_tca.fit > stats_wait, cmat_to_host, eigh, vecs_to_device (core/rf_tca);
+# rf_tca.fit > stats_wait, eigh, and on the host eigensolve cmat_to_host and
+# vecs_to_device around its eigh (core/rf_tca);
 # serve.call > batch_assembly, padded_dispatch > launch, device_wait, fetch
 # (serve/server, serve/dispatcher)
 SPAN_NAMES = (
@@ -188,8 +190,10 @@ def span(name: str, **args):
     Always a ``jax.profiler.TraceAnnotation(name, **args)``: recorded, with
     ``args`` as its stats, only while a profiler session is active.  With a
     :class:`Tracer` installed, also its wall-clock ``B``/``E`` pair (``args``
-    on the ``B`` event).  Call it from Python only, never inside a traced
-    function: there it would mark the trace, not the run.
+    on the ``B`` event).  ``with span(...) as sp`` gives ``sp.set_metadata(
+    **more)``, which adds args known only at the end to both.  Call it from
+    Python only, never inside a traced function: there it would mark the
+    trace, not the run.
     """
     tracer = _TRACER
     if tracer is None:
@@ -197,10 +201,24 @@ def span(name: str, **args):
     return _span_twin(tracer, name, args)
 
 
+class _TwinMetadata:
+    """``set_metadata`` of a span with a Tracer installed: the annotation's
+    stats and the args of its ``B`` event."""
+
+    __slots__ = ("_ann", "_begin")
+
+    def __init__(self, ann: TraceAnnotation, begin: dict):
+        self._ann, self._begin = ann, begin
+
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._begin.setdefault("args", {}).update(args)
+
+
 @contextlib.contextmanager
 def _span_twin(tracer: Tracer, name: str, args: dict):
-    with TraceAnnotation(name, **args), tracer.span(name, args=args or None):
-        yield
+    with TraceAnnotation(name, **args) as ann, tracer.span(name, args=args or None):
+        yield _TwinMetadata(ann, tracer.events[-1])
 
 
 # -- schema validation (the CI bench-smoke contract) --------------------------

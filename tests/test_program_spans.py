@@ -3,14 +3,17 @@ dispatcher's legs, in the JAX profiler's trace and in an installed Tracer.
 
 - a span is a ``TraceAnnotation`` whose args become the event's stats;
 - with a Tracer installed it also writes the wall-clock B/E pair;
-- every eigh path of the fit (stream, fused, dense) emits the four leaf
-  spans under ``rf_tca.fit``; the in-program ``pure_callback`` branch none;
+- every eigh path of the fit (stream, fused, dense) emits its leaf spans
+  under ``rf_tca.fit``: the four of the host eigensolve below 2N = 16m, the
+  wait and the device eigensolve above it; the in-program ``pure_callback``
+  branch none; ``rf_tca.eigh_solves`` counts the solves by path;
 - a serve call emits ``serve.call`` > assembly / padded dispatch > launch,
   device wait, fetch, with the batch's requests, columns and bucket;
 - outputs are bitwise identical with spans recorded and without;
 - the dispatcher's leg log is bounded and its registry keeps two counters.
 """
 import glob
+import sys
 
 import jax
 import numpy as np
@@ -29,6 +32,8 @@ from repro.obs import (
 )
 from repro.serve import AlignerServer, Request
 from repro.serve import dispatcher as dispatcher_mod
+
+rf_tca_mod = sys.modules["repro.core.rf_tca"]  # the package re-exports a function
 
 DIM, N_FEATURES, M = 8, 16, 4
 FIT_LEAVES = ["rf_tca.stats_wait", "rf_tca.cmat_to_host", "rf_tca.eigh",
@@ -114,23 +119,66 @@ def test_span_writes_wall_twin_to_installed_tracer():
     assert len(tracer.events) == 4
 
 
-@pytest.mark.parametrize("fit_kw", [
+FIT_PATHS = [
     {},  # the default streamed path
     {"w_rf": "fused:7"},  # seed-fused statistics, solve_w_rf_gram
     {"mode": "dense", "solver": "eigh"},  # explicit features, solve_w_rf_gram
-], ids=["stream", "fused", "dense"])
-def test_fit_spans_cover_every_eigh_path(fit_kw):
+]
+DEVICE_N_FEATURES = 64  # 2N = 128 >= 16 m: the eigensolve runs on the device
+
+
+@pytest.mark.parametrize("fit_kw,n_features", [
+    *((kw, N_FEATURES) for kw in FIT_PATHS),
+    *((kw, DEVICE_N_FEATURES) for kw in FIT_PATHS),
+], ids=["stream", "fused", "dense", "stream-device", "fused-device", "dense-device"])
+def test_fit_spans_cover_every_eigh_path(fit_kw, n_features):
     xs, xt = _domain()
     with use_tracer(Tracer()) as tracer:
-        rf_tca_fit(xs, xt, n_features=N_FEATURES, m=M, **fit_kw)
-    two_n = 2 * N_FEATURES
-    assert _tree(tracer.events) == [
+        rf_tca_fit(xs, xt, n_features=n_features, m=M, **fit_kw)
+    two_n = 2 * n_features
+    tree = _tree(tracer.events)
+    head = [
         ("rf_tca.fit", 0, {"n": xs.shape[1] + xt.shape[1], "p": DIM,
-                           "n_features": N_FEATURES, "m": M}),
+                           "n_features": n_features, "m": M}),
         ("rf_tca.stats_wait", 1, {}),
-        ("rf_tca.cmat_to_host", 1, {"bytes": 4 * two_n * two_n}),
-        ("rf_tca.eigh", 1, {"two_n": two_n, "m": M}),
-        ("rf_tca.vecs_to_device", 1, {"bytes": 4 * (M + two_n * M)}),
+    ]
+    if n_features == N_FEATURES:  # 2N < 16 m: the host path
+        assert tree == head + [
+            ("rf_tca.cmat_to_host", 1, {"bytes": 4 * two_n * two_n}),
+            ("rf_tca.eigh", 1, {"two_n": two_n, "m": M, "path": "host"}),
+            ("rf_tca.vecs_to_device", 1, {"bytes": 4 * (M + two_n * M)}),
+        ]
+        return
+    # the device path: no copy of C and no upload, the products it took
+    iters = tree[-1][2]["iters"]
+    assert tree == head + [
+        ("rf_tca.eigh", 1, {"two_n": two_n, "m": M, "block": rf_tca_mod.EIGH_BLOCK * M,
+                            "iters": iters, "path": "device"}),
+    ]
+    assert 0 < iters < rf_tca_mod.EIGH_MAX_PRODUCTS
+    assert iters % rf_tca_mod.EIGH_CHECK_EVERY == 0
+
+
+def test_eigh_solves_counts_each_path(monkeypatch):
+    xs, xt = _domain()
+    with use_registry(MetricsRegistry()) as reg:
+        rf_tca_fit(xs, xt, n_features=N_FEATURES, m=M)
+        rf_tca_fit(xs, xt, n_features=DEVICE_N_FEATURES, m=M)
+        rf_tca_fit(xs, xt, n_features=DEVICE_N_FEATURES, m=M, w_rf="fused:7")
+        monkeypatch.setattr(rf_tca_mod, "EIGH_MAX_PRODUCTS", 1)
+        with use_tracer(Tracer()) as tracer:
+            rf_tca_fit(xs, xt, n_features=DEVICE_N_FEATURES, m=M)
+    assert reg.snapshot()["rf_tca.eigh_solves"] == {
+        "path=device": 2, "path=host": 1, "path=host_fallback": 1}
+    # a fallback shows the device attempt, then the host solve with its copies
+    two_n = 2 * DEVICE_N_FEATURES
+    assert [(name, args) for name, _, args in _tree(tracer.events)][1:] == [
+        ("rf_tca.stats_wait", {}),
+        ("rf_tca.eigh", {"two_n": two_n, "m": M, "block": rf_tca_mod.EIGH_BLOCK * M,
+                         "iters": 1, "path": "host_fallback"}),
+        ("rf_tca.cmat_to_host", {"bytes": 4 * two_n * two_n}),
+        ("rf_tca.eigh", {"two_n": two_n, "m": M, "path": "host_fallback"}),
+        ("rf_tca.vecs_to_device", {"bytes": 4 * (M + two_n * M)}),
     ]
 
 
@@ -185,7 +233,7 @@ def test_spans_reach_the_profiler_trace_nested(tmp_path):
         for child in DISPATCH_LEAVES:
             _, s, e, _ = by_name[child][i]
             assert lo <= s and e <= hi
-    assert by_name["rf_tca.eigh"][0][3] == {"two_n": 2 * N_FEATURES, "m": M}
+    assert by_name["rf_tca.eigh"][0][3] == {"two_n": 2 * N_FEATURES, "m": M, "path": "host"}
     assert [ev[3] for ev in by_name["serve.batch_assembly"]] == [
         {"requests": 2, "cols": 12, "bucket": 16}, {"requests": 1, "cols": 3, "bucket": 4}]
 

@@ -1,4 +1,6 @@
 """Streaming RF-TCA solver: scan/Pallas gram paths, SM whitening, eigh vs LOBPCG."""
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,7 @@ from repro.core import (
 )
 from repro.core.rff import draw_omega, rff_features
 from repro.fleet.sharding import sub_jaxprs
+from repro.obs import MetricsRegistry, use_registry
 
 
 @pytest.fixture(scope="module")
@@ -250,3 +253,96 @@ def test_fused_fit_validation(data):
         rf_tca_fit(xs, xt, w_rf="fused:0", mode="dense", **kw)
     with pytest.raises(ValueError, match="fused"):
         rf_tca_fit(xs, xt, w_rf="not-a-spec", **kw)
+
+
+# -- the device eigensolve: blocked subspace iteration on the whitened C ----
+
+rf_tca_mod = sys.modules["repro.core.rf_tca"]  # the package re-exports a function
+
+
+def _whitened_fit_cmat(n_features=256, seed=0):
+    """A whitened C of the fit's own statistics pass: 2N = 512."""
+    rng = np.random.default_rng(seed)
+    xs = jnp.asarray(rng.normal(size=(16, 300)), jnp.float32)
+    xt = jnp.asarray(rng.normal(size=(16, 260)) + 0.5, jnp.float32)
+    _, cmat, _ = rf_tca_mod._fit_stream_stats(
+        xs, xt, jax.random.PRNGKey(seed), 1e-2, 2.0,
+        n_features=n_features, block=128, kernel="gauss")
+    return cmat
+
+
+def _planted_cmat(two_n=512, m=8, gap=None, seed=0):
+    """Symmetric PSD with a decaying spectrum; ``gap`` plants a near-degenerate
+    pair at the m-th place, lambda_{m+1} = (1 - gap) lambda_m."""
+    rng = np.random.default_rng(seed)
+    q = np.linalg.qr(rng.normal(size=(two_n, two_n)))[0]
+    lam = 10.0 * 0.97 ** np.arange(two_n)
+    if gap is not None:
+        lam[m] = lam[m - 1] * (1 - gap)
+    c = (q * lam) @ q.T
+    return jnp.asarray(0.5 * (c + c.T), jnp.float32)
+
+
+def _min_principal_cosine(a, b):
+    qa = np.linalg.qr(np.asarray(a, np.float64))[0]
+    qb = np.linalg.qr(np.asarray(b, np.float64))[0]
+    return np.linalg.svd(qa.T @ qb, compute_uv=False).min()
+
+
+@pytest.mark.parametrize("make", [
+    _whitened_fit_cmat,
+    _planted_cmat,
+    lambda: _planted_cmat(gap=1e-3),
+], ids=["fit_cmat", "decaying", "planted_gap_1e-3"])
+def test_device_eigh_matches_host(make):
+    """Subspace iteration on the device gives LAPACK's top-m pairs: values
+    within 1e-5 relative, the same subspace (smallest principal cosine
+    > 1 - 1e-5), converged before the cap, residuals under the tolerance."""
+    m = 8
+    cmat = make()
+    assert rf_tca_mod._device_eigh_fits(cmat.shape[0], m)
+    vals, vecs, products = rf_tca_mod._device_top_eigh(cmat, m)
+    assert vals is not None and 0 < products < rf_tca_mod.EIGH_MAX_PRODUCTS
+    h_vals, h_vecs = rf_tca_mod._host_top_eigh(np.asarray(cmat), m=m)
+    np.testing.assert_allclose(np.asarray(vals), h_vals, rtol=1e-5)
+    assert _min_principal_cosine(vecs, h_vecs) > 1 - 1e-5
+    c64 = np.asarray(cmat, np.float64)
+    v64 = np.asarray(vecs, np.float64)
+    resid = np.linalg.norm(c64 @ v64 - v64 * np.asarray(vals)[None], axis=0)
+    assert np.all(resid <= 2 * rf_tca_mod.EIGH_RTOL * np.asarray(vals))
+
+
+def test_device_eigh_cap_falls_back_to_host(monkeypatch):
+    """An iteration cap of one product cannot converge: the host path solves
+    instead, with the host path's very result, and the counter says so."""
+    cmat = _whitened_fit_cmat()
+    monkeypatch.setattr(rf_tca_mod, "EIGH_MAX_PRODUCTS", 1)
+    with use_registry(MetricsRegistry()) as reg:
+        vals, vecs = rf_tca_mod._top_eigh(cmat, 8)
+    assert reg.snapshot()["rf_tca.eigh_solves"] == {"path=host_fallback": 1}
+    h_vals, h_vecs = rf_tca_mod._host_top_eigh(np.asarray(cmat), m=8)
+    np.testing.assert_array_equal(np.asarray(vals), h_vals)
+    np.testing.assert_array_equal(np.asarray(vecs), h_vecs)
+
+
+@pytest.mark.parametrize("two_n,m,device", [
+    (512, 8, True), (128, 8, True), (127, 8, False), (32, 4, False), (2048, 32, True),
+    (8192, 32, True), (256, 32, False),
+])
+def test_eigh_path_follows_the_shape(two_n, m, device):
+    """The device solve takes 2N >= 16m: its block of 2m-4m columns is at
+    most a quarter of the matrix."""
+    assert rf_tca_mod._device_eigh_fits(two_n, m) is device
+
+
+def test_default_fit_on_the_device_path_matches_cholesky(data):
+    """rf_tca_fit's default path at 2N = 16m solves on the device and agrees
+    with the dense Cholesky reference."""
+    xs, xt = data
+    kw = dict(n_features=64, m=8, gamma=1e-2, sigma=2.0, seed=0)
+    with use_registry(MetricsRegistry()) as reg:
+        st = rf_tca_fit(xs, xt, **kw)
+    assert reg.snapshot()["rf_tca.eigh_solves"] == {"path=device": 1}
+    ref = rf_tca_fit(xs, xt, mode="dense", solver="cholesky", **kw)
+    np.testing.assert_allclose(np.asarray(st.eigvals), np.asarray(ref.eigvals), rtol=1e-4)
+    assert _min_principal_cosine(st.w_rf, ref.w_rf) > 1 - 1e-4
