@@ -7,7 +7,8 @@ under it: the readings the correctness limits are set from.
 
 ``--plant none`` (the default) runs the program as it is; ``control`` puts the
 plain reference one precision below the configuration's in the program's
-place; any other name is a fault.  Prints one JSON line per seed with
+place; a name of ``WITNESSES`` runs the program under that witness; any other
+name is a fault.  Prints one JSON line per seed with
 ``correct`` and every compared number beside its limit.  ``--sweep`` runs the
 driver's open loop at several offered rates on one server instead.  The
 benchmark's own runs never run this.
@@ -27,21 +28,22 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--plant", default="none",
-                    help="none, control, or a fault of chipbench/faults.py")
+                    help="none, control, or a witness or fault of chipbench/faults.py")
     ap.add_argument("--seconds", type=float, default=5.0, help="window of each run")
     ap.add_argument("--sweep", default=None,
                     help="comma-separated offered rates: run the driver's sweep instead")
     args = ap.parse_args(argv)
     sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
     from chipbench import harness
-    from chipbench.faults import CONTROLS, FAULTS
+    from chipbench.faults import CONTROLS, FAULTS, WITNESSES
 
     cell = harness.load_json(harness.BENCH_DIR, "cells", args.workload)
     seeds = [int(s) for s in args.seeds.split(",")]
     if args.sweep:
         sweep(args, cell, seeds)
         return 0
-    plants = {"control": CONTROLS.get(cell["driver"]), **FAULTS.get(cell["driver"], {})}
+    plants = {"control": CONTROLS.get(cell["driver"]), **FAULTS.get(cell["driver"], {}),
+              **WITNESSES.get(cell["driver"], {})}
     for seed in seeds:
         t0 = time.perf_counter()
         if args.plant == "none":

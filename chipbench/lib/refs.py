@@ -227,6 +227,12 @@ def transform_columns(w_rf, omega, x, *, width: int = 256, precision: str = "hig
 # --------------------------------------------------------------------------
 
 
+def fed_omega(seed: int, n_rff: int, d: int, sigma: float = 1.0):
+    """The shared Omega (N, d) of Algorithm 5, drawn with jax.random from
+    PRNGKey(seed) as the protocol documents."""
+    return jax.random.normal(jax.random.PRNGKey(seed), (n_rff, d)) / sigma
+
+
 def fed_init(key, input_dim: int, widths: tuple, n_rff: int, m: int, n_classes: int):
     """The shared initial model every client starts from (paper Fig. 1):
     He-normal extractor layers, W_RF ~ N(0, 1/2N), classifier ~ N(0, 1/m)."""
@@ -295,7 +301,7 @@ def fed_round(src, src_opt, tgt, tgt_opt, batch, plan, *, omega, lr, lam, n_clas
     ``src``/``src_opt``: lists over clients; ``batch``: per-client lists
     ``xs``, ``ys``, ``x_msg`` and the target's ``xt``, ``xt_msg``; ``plan``:
     (A, B, C) client-index lists.  Returns the new state and each source's
-    and the target's gradient."""
+    and the target's gradient (None for a target that took no step)."""
     dot = DOTS[precision]
     a_set, b_set, c_set = (set(s) for s in plan)
     k = len(src)
@@ -331,12 +337,50 @@ def fed_round(src, src_opt, tgt, tgt_opt, batch, plan, *, omega, lr, lam, n_clas
     return new_src, new_opt, tgt, tgt_opt, src_grads, tgt_grad
 
 
-def leaf_gap(prog_leaves, ref_leaves, keep) -> float:
-    """Worst leaf's |norm(program) - norm(reference)| over the larger of the
-    reference leaf's norm and the median reference leaf norm, over the leaves
+# one compiled program per plan and kind of round; ``plan`` as a tuple of tuples
+fed_round_jit = jax.jit(fed_round, static_argnames=(
+    "plan", "lr", "lam", "n_classes", "classifier_round", "precision"))
+
+
+def fed_round_from(cfg, proto, src, src_opt, tgt, tgt_opt, batch, t, plan, *,
+                   precision: str = "highest"):
+    """``fed_round`` as round ``t`` of a federation with client settings
+    ``cfg`` and protocol settings ``proto``: the Omega the protocol documents,
+    ``plan``'s ``msg_clients``, ``w_clients`` and ``c_clients`` as A, B, C, and a
+    classifier round where t = 0 mod T_C."""
+    return fed_round_jit(
+        src, src_opt, tgt, tgt_opt, batch,
+        tuple(tuple(s) for s in (plan.msg_clients, plan.w_clients, plan.c_clients)),
+        omega=fed_omega(cfg.rff_seed, cfg.n_rff, cfg.extractor_widths[-1], cfg.rff_sigma),
+        lr=proto.lr, lam=cfg.lambda_mmd, n_classes=cfg.n_classes,
+        classifier_round=(t % proto.t_c == 0), precision=precision)
+
+
+def leaf_gaps(prog_leaves, ref_leaves, keep) -> np.ndarray:
+    """Each leaf's |norm(program) - norm(reference)| over the larger of the
+    reference leaf's norm and the median reference leaf norm, for the leaves
     ``keep`` marks."""
     ref_n = np.asarray([float(jnp.linalg.norm(r)) for r in ref_leaves])
     prog_n = np.asarray([float(jnp.linalg.norm(p)) for p in prog_leaves])
     med = float(np.median(ref_n[keep])) if np.any(keep) else 0.0
     gaps = np.abs(prog_n - ref_n) / np.maximum(np.maximum(ref_n, med), 1e-30)
-    return float(np.max(gaps[keep])) if np.any(keep) else 0.0
+    return gaps[keep]
+
+
+def leaf_gap(prog_leaves, ref_leaves, keep) -> float:
+    """The worst of ``leaf_gaps`` (0 with no leaf kept)."""
+    gaps = leaf_gaps(prog_leaves, ref_leaves, keep)
+    return float(np.max(gaps)) if gaps.size else 0.0
+
+
+def leaf_rel(prog_leaves, ref_leaves, keep) -> float:
+    """Worst leaf's norm(program - reference) over the larger of the
+    reference leaf's norm and the median reference leaf norm, over the leaves
+    ``keep`` marks (float64 on the host)."""
+    ref = [np.asarray(r, np.float64) for r in ref_leaves]
+    ref_n = np.asarray([np.linalg.norm(r) for r in ref])
+    diff_n = np.asarray([np.linalg.norm(np.asarray(p, np.float64) - r)
+                         for p, r in zip(prog_leaves, ref)])
+    med = float(np.median(ref_n[keep])) if np.any(keep) else 0.0
+    rel = diff_n / np.maximum(np.maximum(ref_n, med), 1e-30)
+    return float(np.max(rel[keep])) if np.any(keep) else 0.0

@@ -75,3 +75,14 @@ def test_dot_high_is_three_bf16_passes():
     err_bf16 = np.abs(np.asarray(jnp.matmul(a.astype(jnp.bfloat16), a.astype(jnp.bfloat16),
                                             preferred_element_type=jnp.float32)) - exact).max()
     assert err_f32 < err_high < err_bf16
+
+
+def test_leaf_gaps_one_leaf_off_moves_the_worst_and_not_the_median():
+    ref = [np.ones(4), np.ones(9), np.ones(16)]  # norms 2, 3, 4
+    keep = np.ones(3, bool)
+    one_off = [ref[0] * 1.01, ref[1], ref[2]]
+    all_off = [r * 1.01 for r in ref]
+    assert refs.leaf_gap(one_off, ref, keep) == pytest.approx(0.02 / 3, rel=1e-5)
+    assert np.median(refs.leaf_gaps(one_off, ref, keep)) == 0
+    assert np.median(refs.leaf_gaps(all_off, ref, keep)) == pytest.approx(0.01, rel=1e-5)
+    assert refs.leaf_gaps(all_off, ref, np.asarray([True, False, True])).size == 2

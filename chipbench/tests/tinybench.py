@@ -25,6 +25,16 @@ TINY_TRAFFIC = {
     "rounds": {"n_rff": 16, "m": 4},
     "serve": {"n_features": 16, "m": 4, "width_hi": 64},
 }
+# The tiny cells' limits, set from seeds at this size on the CPU (at the cells'
+# sizes the limits come from chip runs).  Fit, 13 seeds: the program reads
+# eig_rel <= 2.3e-6 and w_resid <= 3.0e-6, the control >= 5.7e-6 and >= 1.3e-5.
+# Rounds, 44 seeds: the program reads grad_gap <= 1.8e-7, change_gap <= 1.9e-6,
+# round_grad_gap <= 5.2e-7, round_grad_rel <= 1.7e-6 and round_change_gap
+# <= 5.9e-6; the control, over 8 seeds, >= 3.0e-4, 3.0e-5, 1.3e-3, 2.9e-3 and
+# 2.0e-4.
+TINY_LIMITS = {"fit": {"eig_rel": 4e-6, "w_resid": 6e-6},
+               "rounds": {"grad_gap": 2e-5, "change_gap": 1e-5, "round_grad_gap": 3e-5,
+                          "round_grad_rel": 1e-4, "round_change_gap": 3e-5}}
 
 
 def make(tmp_path: Path) -> Path:
@@ -36,7 +46,8 @@ def make(tmp_path: Path) -> Path:
     write(bench, "configs", "tiny", config)
     for driver, traffic in TINY_TRAFFIC.items():
         cell = json.loads((bench / "cells" / f"office31.{driver}.json").read_text())
-        cell.update(config="tiny", trace_seconds=0.3, traffic={**cell["traffic"], **traffic})
+        cell.update(config="tiny", trace_seconds=0.3, traffic={**cell["traffic"], **traffic},
+                    limits={**cell["limits"], **TINY_LIMITS.get(driver, {})})
         write(bench, "cells", f"tiny.{driver}", cell)
     return bench
 
